@@ -29,7 +29,6 @@ def _hlo_flops(method, layer, batch=1):
                              jnp.float32)
     c = jax.jit(lambda x, w: deconv_nd(x, w, layer.stride, 0,
                                        method=method)).lower(x, w).compile()
-    # cost_analysis_dict keeps the jax<0.4.x list-of-dicts shim in ONE place
     return float(cost_analysis_dict(c).get("flops", 0.0))
 
 

@@ -408,11 +408,14 @@ def _quantized_rows(rng, rec) -> dict:
                 assert counts.get("conv_general_dilated", 0) == 0, counts
                 # acceptance: int8 weights change the working set, NOT the
                 # launch structure — dispatch counts equal the f32 engine,
-                # per-step VMEM bytes drop at every layer
+                # per-step VMEM bytes never grow and drop overall (a thin
+                # weight block pads to the same tiles at either width)
                 assert report.mxu_dispatches == f32_rep.mxu_dispatches
                 assert report.grid_steps == f32_rep.grid_steps
                 for rq, rf in zip(report.layers, f32_rep.layers):
-                    assert rq.vmem_bytes < rf.vmem_bytes, (rq, rf)
+                    assert rq.vmem_bytes <= rf.vmem_bytes, (rq, rf)
+                assert (sum(r.vmem_bytes for r in report.layers)
+                        < sum(r.vmem_bytes for r in f32_rep.layers))
                 schedules[f"q8_{name}"] = report.to_json()
             err = float(np.max(np.abs(outs[method] - y_f32)))
             assert err <= tol, (name, method, err, tol)

@@ -12,9 +12,11 @@ recovery, and the run ends with the server's health/stats surface.
 import argparse
 import time
 
+import jax
 import numpy as np
 
 from repro import obs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime.dcnn_server import (
     DcnnServer,
     ServeRequest,
@@ -35,6 +37,7 @@ def main():
                     help="write the telemetry spine's event log (spans + "
                          "final metric snapshots) to this JSONL path")
     args = ap.parse_args()
+    print(f"compile cache: {enable_compile_cache()}")
 
     faults = None
     if args.inject_faults:
@@ -72,8 +75,9 @@ def main():
     dt = time.perf_counter() - t0
 
     stats = server.stats()
+    dev = jax.devices()[0]
     print(f"\nserved {served} requests in {dt:.2f}s "
-          f"({served / dt:.1f} req/s on CPU interpret)")
+          f"({served / dt:.1f} req/s on {dev.platform} {dev.device_kind})")
     cache = stats["schedule_cache"]
     print(f"schedule cache: {cache['size']} resident, "
           f"{cache['hits']} hits / {cache['misses']} compiles")

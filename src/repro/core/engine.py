@@ -259,6 +259,14 @@ class EngineConfig:
     def vmem_budget(self) -> int:
         return self.max_tile_bytes or _tiling.DECONV_VMEM_BUDGET
 
+    @property
+    def pallas_interpret(self) -> bool:
+        """Whether the Pallas kernels run in interpret mode: ``interpret``
+        when set, else everywhere but a TPU backend."""
+        if self.interpret is not None:
+            return self.interpret
+        return _kcommon.default_interpret()
+
 
 class UniformEngine:
     """The configured engine: both op directions + a compiled plan cache.
@@ -345,7 +353,10 @@ class UniformEngine:
                     vmem_budget=cfg.vmem_budget, block_ci=cfg.block_ci,
                     block_co=cfg.block_co, groups=groups, dilation=dilation,
                     backward=backward, in_dtype_bytes=in_dtype_bytes,
-                    w_dtype_bytes=w_bytes)
+                    w_dtype_bytes=w_bytes,
+                    # Mosaic lowers only lane-legal channel blocks
+                    lane_legal=(cfg.method == "pallas"
+                                and not cfg.pallas_interpret))
                 self.plan_sources["heuristic"] += 1
             if tel is not None:
                 tel.registry.counter("engine_plan_cache_misses_total").inc()

@@ -197,7 +197,8 @@ def plan_uniform_tiles(in_spatial, kernel, stride, cin, cout, *,
                        in_dtype_bytes: int = 2,
                        w_dtype_bytes: int | None = None,
                        groups: int = 1,
-                       dilation=None) -> DeconvTilePlan:
+                       dilation=None,
+                       lane_legal: bool = False) -> DeconvTilePlan:
     """Jointly pick ``(dtile, block_ci, block_co)`` against the VMEM budget.
 
     The SHARED planner entry for both directions of the uniform engine:
@@ -232,6 +233,12 @@ def plan_uniform_tiles(in_spatial, kernel, stride, cin, cout, *,
     ``w_dtype_bytes`` (default: ``in_dtype_bytes``) is the planner width
     of a weight element — 1 for int8-quantized weights, so quantized
     plans budget (and report) the genuinely smaller working set.
+
+    ``lane_legal=True`` keeps every channel block Mosaic can lower: a
+    block's lane extent must be the whole (per-group) channel dim or a
+    multiple of 128, so the default ``min(C, 128)`` blocks never shrink and
+    only the spatial tile adapts (the engine asks for this whenever it
+    compiles its kernels for a TPU).
     """
     d_eff, step_bytes = step_byte_model(
         in_spatial, kernel, stride, mode=mode, backward=backward,
@@ -245,10 +252,10 @@ def plan_uniform_tiles(in_spatial, kernel, stride, cin, cout, *,
     if allow_split:
         while dtile > 1 and step_bytes(dtile, bci, bco) > vmem_budget:
             dtile = -(-dtile // 2)
-    if block_co is None:
+    if block_co is None and not lane_legal:
         while step_bytes(dtile, bci, bco) > vmem_budget and bco > 8:
             bco //= 2
-    if block_ci is None:
+    if block_ci is None and not lane_legal:
         while step_bytes(dtile, bci, bco) > vmem_budget and bci > 8:
             bci //= 2
     n_dt = -(-d_eff // dtile)

@@ -22,14 +22,26 @@ from __future__ import annotations
 import itertools
 import math
 
+import jax
 import jax.numpy as jnp
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.functional import _canon
 
-# JAX 0.4.x exposes TPUCompilerParams; newer JAX renamed it CompilerParams.
-CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
+# Scoped-VMEM limit handed to Mosaic: the modeled working set plus headroom
+# for compiler temporaries, at least 32 MiB, and capped under the 128 MiB
+# of VMEM a v5e core has.
+VMEM_LIMIT_FLOOR = 32 * 1024 * 1024
+VMEM_LIMIT_CAP = 100 * 1024 * 1024
+
+
+def compiler_params(step_bytes: int, semantics) -> pltpu.CompilerParams:
+    """Mosaic params for one engine kernel: grid semantics plus a scoped
+    VMEM limit derived from the same byte model the planner budgets."""
+    limit = min(VMEM_LIMIT_CAP,
+                max(VMEM_LIMIT_FLOOR, step_bytes * 3 // 2 + (4 << 20)))
+    return pltpu.CompilerParams(dimension_semantics=tuple(semantics),
+                                vmem_limit_bytes=int(limit))
 
 
 def canon_dilation(dilation, rank):
@@ -201,8 +213,120 @@ def operand_plan_bytes(dtype) -> int:
 
 def default_interpret() -> bool:
     """Pallas interpret-mode default: emulate everywhere but real TPUs."""
-    import jax
     return jax.default_backend() != "tpu"
+
+
+# -- Flattened tile layout (what the kernel bodies see) ----------------------
+#
+# Mosaic lowers 2-D [rows, lanes] values well and higher-rank reshapes,
+# strided value slices and batched 3-D contractions poorly.  So every kernel
+# block is a 2-D slab: one leading-dim tile of ``dtile`` rows with its two
+# trailing spatial dims zero-padded onto a (Lh, Lw) grid and flattened,
+# ``[dtile*Lh*Lw, C]``.  On that grid a spatial shift by tap ``m`` is ONE
+# static row offset ``m_d*Lh*Lw + m_h*Lw + m_w``: the grid is wide enough
+# (``L = extent + M - 1``) that a shifted valid element never wraps onto
+# another valid element, so wrapped terms only ever touch padding positions,
+# which the host crops.  Stride phases are split (inputs) and interleaved
+# (outputs) on the host, so no kernel strides or transposes a value.
+
+def round_up(v: int, m: int) -> int:
+    return -(-v // m) * m
+
+
+def tile_bytes(rows: int, cols: int, dtype_bytes: int) -> int:
+    """VMEM bytes of a [rows, cols] slab in Mosaic's tiled layout: lanes
+    pad to 128, sublanes to 8 rows of 32-bit words (16 bf16, 32 int8)."""
+    sub = 8 * max(1, 4 // dtype_bytes)
+    return round_up(max(rows, 1), sub) * round_up(max(cols, 1), 128) \
+        * dtype_bytes
+
+
+def lift_geometry3(spatial, kernel, stride, dilation=None):
+    """Lift a rank-1/2 (spatial, kernel, stride, dilation) geometry onto
+    the canonical rank-3 layout the kernels run on (``lift_3d``'s rule)."""
+    rank = len(tuple(spatial))
+    dil = canon_dilation(dilation, rank)
+    return tuple(lift_tuple3(v, rank) for v in (spatial, kernel, stride, dil))
+
+
+def flat_grid(extent, m_max):
+    """(Lh, Lw): the trailing-dim grid a kernel flattens its slabs onto."""
+    return tuple(e + m - 1 for e, m in zip(extent, m_max))
+
+
+def fit_axis(a, axis: int, size: int):
+    """Zero-pad or crop ``axis`` of ``a`` to exactly ``size``."""
+    cur = a.shape[axis]
+    if cur > size:
+        return jax.lax.slice_in_dim(a, 0, size, axis=axis)
+    if cur < size:
+        widths = [(0, 0)] * a.ndim
+        widths[axis] = (0, size - cur)
+        a = jnp.pad(a, widths)
+    return a
+
+
+def to_tiles(a, n_dt: int, grid):
+    """[N, n_dt*dtile, h, w, C] -> [N, n_dt, dtile*Lh*Lw, C] on ``grid``."""
+    a = fit_axis(fit_axis(a, 2, grid[0]), 3, grid[1])
+    n, d, c = a.shape[0], a.shape[1], a.shape[-1]
+    return a.reshape(n, n_dt, (d // n_dt) * grid[0] * grid[1], c)
+
+
+def from_tiles(a, grid):
+    """Inverse of ``to_tiles``: [N, n_dt, R, C] -> [N, n_dt*R/(Lh*Lw), Lh,
+    Lw, C]."""
+    n, n_dt, r, c = a.shape
+    return a.reshape(n, n_dt * r // (grid[0] * grid[1]), grid[0], grid[1], c)
+
+
+def to_phases(a, stride, n_dt: int, grid):
+    """Split strided rows into phases: [N, n_dt*dtile*S_d, H', W', C] ->
+    [N, n_dt, prod(S), dtile*Lh*Lw, C] with phase ``p`` (row-major over
+    the per-dim phases, the ``phase_taps`` order) holding ``a[u*S + p]``
+    on the (dtile, Lh, Lw) grid of each tile."""
+    sd, sh, sw = stride
+    a = fit_axis(fit_axis(a, 2, grid[0] * sh), 3, grid[1] * sw)
+    n, d, c = a.shape[0], a.shape[1], a.shape[-1]
+    dtile = d // (n_dt * sd)
+    a = a.reshape(n, n_dt, dtile, sd, grid[0], sh, grid[1], sw, c)
+    a = a.transpose(0, 1, 3, 5, 7, 2, 4, 6, 8)
+    return a.reshape(n, n_dt, sd * sh * sw, dtile * grid[0] * grid[1], c)
+
+
+def from_phases(a, stride, grid):
+    """Interleave phases: the inverse of ``to_phases`` —
+    [N, n_dt, prod(S), dtile*Lh*Lw, C] -> [N, n_dt*dtile*S_d, Lh*S_h,
+    Lw*S_w, C] with ``out[q*S + p] = a[p, q]``."""
+    sd, sh, sw = stride
+    n, n_dt, _, r, c = a.shape
+    dtile = r // (grid[0] * grid[1])
+    a = a.reshape(n, n_dt, sd, sh, sw, dtile, grid[0], grid[1], c)
+    a = a.transpose(0, 1, 5, 2, 6, 3, 7, 4, 8)
+    return a.reshape(n, n_dt * dtile * sd, grid[0] * sh, grid[1] * sw, c)
+
+
+def mxu_dtype(*dtypes):
+    """Common operand dtype of an in-kernel matmul: integer operands widen
+    to f32 (|q| <= 127 is exact), floats promote to the wider one."""
+    if any(jnp.issubdtype(d, jnp.integer) for d in dtypes):
+        return jnp.dtype(jnp.float32)
+    return jnp.dtype(jnp.result_type(*dtypes))
+
+
+def mxu_precision(dtype):
+    """f32 operands contract at full f32 precision (the engine's f32
+    contract); narrower floats take the MXU's native pass."""
+    return (jax.lax.Precision.HIGHEST if jnp.dtype(dtype) == jnp.float32
+            else None)
+
+
+def phase_weight_slab(w_ref, off: int, n: int, dtype):
+    """The phase's ``n`` contiguous taps of a [prod(K), a, b] weight block
+    side by side as ONE [a, n*b] operand (a lane concatenation), so the
+    whole phase is a single MXU matmul."""
+    ws = [w_ref[off + t].astype(dtype) for t in range(n)]
+    return ws[0] if n == 1 else jnp.concatenate(ws, axis=1)
 
 
 # -- Host-side canonicalisation shared by both ops layers --------------------

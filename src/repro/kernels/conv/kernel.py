@@ -17,15 +17,16 @@ Same fused 4D grid as the deconv forward:
     into an f32 VMEM scratch across Cin blocks.
   * y[o] = sum_k x[o*S + k] · w[k] (VALID, correlation convention — the
     caller pads (lo, hi) host-side).  Taps are gathered from the S^d *input*
-    phases of x: for phase p, ``x_ph = x[p::S]`` feeds ONE wide MXU matmul
-    against the phase's valid taps (phase-major weight layout) — S^d
-    dispatches per grid step, not K^d.  Stride 1 is the degenerate single
-    phase (one matmul carrying all K^d taps).
+    phases of x, split outside the kernel (``kernels.common.to_phases``):
+    phase ``x_ph = x[p::S]``, a flattened [dtile*Lh*Lw, bci] slab, feeds ONE
+    wide MXU matmul against the phase's valid taps (phase-major weight
+    layout) — S^d dispatches per grid step, not K^d.  Stride 1 is the
+    degenerate single phase (one matmul carrying all K^d taps).
   * each grid tile owns ``dtile`` output rows and reads the aligned
-    ``dtile*S_d`` input rows; when K_d > S_d a tap reaches into the NEXT
-    tile's input slab, so the d-tile axis iterates in REVERSE and the spill
-    rides a VMEM halo carry (the FIFO-D exchange running backward) —
-    recursive, so K_d >> S_d*dtile composes.
+    ``dtile`` rows of every input phase; when K_d > S_d a tap reaches into
+    the NEXT tile's input slab, so the d-tile axis iterates in REVERSE and
+    the spill rides a VMEM halo carry (the FIFO-D exchange running
+    backward) — recursive, so K_d >> S_d*dtile composes.
   * 2D/1D are the degenerate singleton-dim cases; ``ops.py`` lifts inputs
     as [N, H, 1, W, C] so the large image dim lands on the tileable axis.
 
@@ -48,26 +49,46 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import (
-    CompilerParams,
     apply_epilogue,
+    compiler_params,
+    flat_grid,
+    from_tiles,
     halo_depth,
+    lift_geometry3,
+    mxu_dtype,
+    mxu_precision,
     phase_geometry,
     phase_taps,
+    phase_weight_slab,
+    tile_bytes,
+    to_phases,
 )
 
 
-def _conv_kernel_body(*refs, tile_spatial, kernel, stride, dilation,
-                      n_ci_blocks, out_dtype, has_scale=False,
-                      has_bias=False, activation="none", alpha=0.2):
+def _conv_kernel_body(*refs, rows, plane, row_w, margin, dtile, halo,
+                      kernel, stride, dilation, n_ci_blocks, out_dtype,
+                      has_scale=False, has_bias=False, activation="none",
+                      alpha=0.2):
     """One grid step: a (batch, co-block, d-tile, ci-block) partial conv.
 
-    x_ref:   [1, dtile*S_d, IH, IW, bci]   (aligned input slab of tile t)
-    w_ref:   [prod(K), bco, bci]           (phase-major tap order)
-    s_ref:   [1, bco]                      (only when ``has_scale``)
-    b_ref:   [1, bco]                      (only when ``has_bias``)
-    o_ref:   [1, dtile, OH, OW, bco]       (this tile's output slab)
-    acc_ref: VMEM f32 [dtile + M_d - 1, OH, OW, bco]
-    halo_ref: VMEM f32 [M_d - 1, OH, OW, bco] (None if M_d == 1)
+    Blocks are flattened slabs on the tile's (Lh, Lw) output grid (see
+    ``kernels.common``), ``plane = Lh*Lw`` rows per leading-dim row:
+
+    x_ref:   [1, 1, prod(S), rows, bci]  (host-split input phases of tile t)
+    w_ref:   [prod(K), bci, bco]         (phase-major tap order)
+    s_ref:   [1, bco]                    (only when ``has_scale``)
+    b_ref:   [1, bco]                    (only when ``has_bias``)
+    o_ref:   [1, 1, rows, bco]           (this tile's output slab)
+    acc_ref: VMEM f32 [margin + (dtile + M_d - 1)*plane, bco]
+    halo_ref: VMEM f32 [(M_d - 1)*plane, bco] (None if M_d == 1)
+
+    Input phase row ``u`` feeds output row ``u - m`` through tap ``m``:
+    each phase is ONE matmul whose tap columns overlap-add at the row
+    offset ``margin + (M_d-1-m_d)*plane - m_h*Lw - m_w`` (the ``margin``
+    head keeps every offset non-negative; it only ever collects wrapped
+    terms of padding positions).  Accumulator rows ``[margin, margin +
+    (M_d-1)*plane)`` belong to the previous tile and ride the reversed
+    FIFO-D carry.
 
     The epilogue (scale + bias + activation) runs in ``_flush`` — after the
     Cin adder tree completes AND after the reversed FIFO-D carry-in, so it
@@ -81,77 +102,65 @@ def _conv_kernel_body(*refs, tile_spatial, kernel, stride, dilation,
     s_ref = next(it) if has_scale else None
     b_ref = next(it) if has_bias else None
     o_ref, acc_ref = next(it), next(it)
-    rest = list(it)
-    halo_ref = rest[0] if rest else None
-    quantized = (jnp.issubdtype(x_ref.dtype, jnp.integer)
-                 or jnp.issubdtype(w_ref.dtype, jnp.integer))
+    halo_ref = next(it, None)
     r = pl.program_id(2)
     cb = pl.program_id(3)
-    m_max = phase_geometry(kernel, stride, dilation)
-    halo = halo_depth(kernel, stride, dilation)
-    dtile, oh, ow = tile_spatial
+    cdt = mxu_dtype(x_ref.dtype, w_ref.dtype)
+    bco = acc_ref.shape[-1]
 
     @pl.when(cb == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[0]                                    # [dtile*S_d, IH, IW, bci]
-    if quantized:
-        x = x.astype(jnp.float32)
-    bci = x.shape[-1]
-
     off = 0
-    for _, p, taps in phase_taps(kernel, stride, dilation):
-        # gather input phase p once: x_ph[u] = x[u*S + p]
-        x_ph = x[tuple(slice(pj, None, sj) for pj, sj in zip(p, stride))]
-        lh, lw = x_ph.shape[1], x_ph.shape[2]
-        # one wide matmul per phase: [dtile*Lh*Lw, bci] x [n_taps, bco, bci]
-        w_taps = w_ref[off:off + len(taps)]
-        if quantized:
-            w_taps = w_taps.astype(jnp.float32)
+    for p_idx, _, taps in phase_taps(kernel, stride, dilation):
+        x = x_ref[0, 0, p_idx].astype(cdt)          # input phase p
+        # one wide matmul per phase: [rows, bci] x [bci, n_taps*bco]
+        w = phase_weight_slab(w_ref, off, len(taps), cdt)
         off += len(taps)
-        res = jax.lax.dot_general(
-            x_ph.reshape(-1, bci), w_taps, (((1,), (2,)), ((), ())),
-            preferred_element_type=jnp.float32)   # [dtile*Lh*Lw, n_taps, bco]
-        res = res.reshape(dtile, lh, lw, len(taps), -1)
+        res = jnp.dot(x, w, preferred_element_type=jnp.float32,
+                      precision=mxu_precision(cdt))
         for t_idx, m in enumerate(taps):
-            # y[o, h, w] += res[o + m_d, h + m_h, w + m_w, tap]; the leading
-            # shift lands in the accumulator (carry rows at the top)
-            win = res[:, m[1]:m[1] + oh, m[2]:m[2] + ow, t_idx]
-            j0 = m_max[0] - 1 - m[0]
-            acc_ref[j0:j0 + dtile] += win
+            # y[o] += x_p[o + m] w_tap: row u of the phase lands at u - m;
+            # the leading shift reaches into the carry rows at the top
+            at = margin + (halo - m[0]) * plane - m[1] * row_w - m[2]
+            acc_ref[pl.ds(at, rows), :] += \
+                res[:, t_idx * bco:(t_idx + 1) * bco]
 
+    last = cb == n_ci_blocks - 1
     if halo:
+        hp = halo * plane
+
         # reversed FIFO-D: the previous (reversed) step worked on tile t+1
         # and deposited its spill into THIS tile's tail rows ...
-        @pl.when(jnp.logical_and(cb == n_ci_blocks - 1, r > 0))
+        @pl.when(jnp.logical_and(last, r > 0))
         def _carry_in():
-            acc_ref[dtile:] += halo_ref[...]
+            acc_ref[pl.ds(margin + dtile * plane, hp), :] += halo_ref[...]
 
         # ... and this tile's head rows (outputs of tile t-1, read AFTER the
         # carry-in so deep halos compose) are left for the next step.
-        @pl.when(cb == n_ci_blocks - 1)
+        @pl.when(last)
         def _carry_out():
-            halo_ref[...] = acc_ref[:halo]
+            halo_ref[...] = acc_ref[pl.ds(margin, hp), :]
 
-    @pl.when(cb == n_ci_blocks - 1)
+    @pl.when(last)
     def _flush():
-        y = apply_epilogue(acc_ref[halo:],
-                           b_ref[0] if b_ref is not None else None,
+        y = apply_epilogue(acc_ref[pl.ds(margin + halo * plane, rows), :],
+                           b_ref[...] if b_ref is not None else None,
                            activation, alpha,
-                           scale=s_ref[0] if s_ref is not None else None)
-        o_ref[0] = y.astype(out_dtype)
+                           scale=s_ref[...] if s_ref is not None else None)
+        o_ref[0, 0] = y.astype(out_dtype)
 
 
 def conv_pallas_3d(x: jax.Array, w_taps: jax.Array, *,
                    kernel: Sequence[int], stride: Sequence[int],
                    block_ci: int, block_co: int, dtile: int,
+                   interpret: bool,
                    dilation: Sequence[int] | None = None,
                    groups: int = 1,
                    scale: jax.Array | None = None,
                    bias: jax.Array | None = None,
                    activation: str = "none", alpha: float = 0.2,
-                   interpret: bool = True,
                    out_dtype=None) -> jax.Array:
     """Uniform strided conv on rank-3 canonical layout — one ``pallas_call``.
 
@@ -167,7 +176,8 @@ def conv_pallas_3d(x: jax.Array, w_taps: jax.Array, *,
     group's input slab — grouped/depthwise layers stay ONE pallas_call.
     ``bias``/``activation`` fuse the layer epilogue into the kernel flush.
     Returns [N, n_dtiles*dtile, OH, OW, Co]; rows at or beyond the true
-    output extent are cropped by the caller.
+    output extent are cropped by the caller.  The stride-phase split of x
+    and the flattening around the kernel are plain XLA reshapes.
     """
     n, d_in, ih, iw, ci = x.shape
     co = w_taps.shape[1]
@@ -190,26 +200,34 @@ def conv_pallas_3d(x: jax.Array, w_taps: jax.Array, *,
     n_ci, n_co = cig // block_ci, co // block_co
     assert n_co % groups == 0, (n_co, groups)
     nco_g = n_co // groups              # output blocks per group
+    m_max = phase_geometry(kernel, stride, dilation)
     halo = halo_depth(kernel, stride, dilation)
-    tile_spatial = (dtile, oh, ow)
+    grid_hw = flat_grid((oh, ow), m_max[1:])
+    plane = grid_hw[0] * grid_hw[1]
+    rows = dtile * plane
+    margin = (m_max[1] - 1) * grid_hw[1] + m_max[2] - 1
 
     body = functools.partial(
-        _conv_kernel_body, tile_spatial=tile_spatial, kernel=kernel,
-        stride=stride, dilation=dilation, n_ci_blocks=n_ci,
-        out_dtype=out_dtype, has_scale=scale is not None,
-        has_bias=bias is not None, activation=activation, alpha=alpha)
-    scratch = [pltpu.VMEM((dtile + halo, oh, ow, block_co), jnp.float32)]
+        _conv_kernel_body, rows=rows, plane=plane, row_w=grid_hw[1],
+        margin=margin, dtile=dtile, halo=halo, kernel=kernel, stride=stride,
+        dilation=dilation, n_ci_blocks=n_ci, out_dtype=out_dtype,
+        has_scale=scale is not None, has_bias=bias is not None,
+        activation=activation, alpha=alpha)
+    scratch = [pltpu.VMEM((margin + (dtile + halo) * plane, block_co),
+                          jnp.float32)]
     if halo:
-        scratch.append(pltpu.VMEM((halo, oh, ow, block_co), jnp.float32))
+        scratch.append(pltpu.VMEM((halo * plane, block_co), jnp.float32))
 
     in_specs = [
-        pl.BlockSpec((1, dtile * stride[0], ih, iw, block_ci),
+        pl.BlockSpec((1, 1, math.prod(stride), rows, block_ci),
                      lambda b, oc, t, ic: (b, n_dt - 1 - t, 0, 0,
                                            (oc // nco_g) * n_ci + ic)),
-        pl.BlockSpec((math.prod(kernel), block_co, block_ci),
-                     lambda b, oc, t, ic: (0, oc, ic)),
+        pl.BlockSpec((math.prod(kernel), block_ci, block_co),
+                     lambda b, oc, t, ic: (0, ic, oc)),
     ]
-    operands = [x, w_taps]
+    # the kernel contracts the MIDDLE weight dim: [prod(K), Ci/G, Co]
+    operands = [to_phases(x, stride, n_dt, grid_hw),
+                jnp.swapaxes(w_taps, 1, 2)]
     if scale is not None:
         in_specs.append(pl.BlockSpec((1, block_co),
                                      lambda b, oc, t, ic: (0, oc)))
@@ -219,22 +237,24 @@ def conv_pallas_3d(x: jax.Array, w_taps: jax.Array, *,
                                      lambda b, oc, t, ic: (0, oc)))
         operands.append(bias.reshape(1, co))
 
-    grid = (n, n_co, n_dt, n_ci)
-    return pl.pallas_call(
+    step = vmem_bytes((n_dt * dtile, oh, ow), kernel, stride, block_ci,
+                      block_co, jnp.dtype(x.dtype).itemsize, dtile=dtile,
+                      dilation=dilation,
+                      w_dtype_bytes=jnp.dtype(w_taps.dtype).itemsize,
+                      out_dtype_bytes=jnp.dtype(out_dtype).itemsize)
+    y = pl.pallas_call(
         body,
-        grid=grid,
+        grid=(n, n_co, n_dt, n_ci),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, dtile, oh, ow, block_co),
-                               lambda b, oc, t, ic: (b, n_dt - 1 - t, 0, 0,
-                                                     oc)),
-        out_shape=jax.ShapeDtypeStruct((n, n_dt * dtile, oh, ow, co),
-                                       out_dtype),
+        out_specs=pl.BlockSpec((1, 1, rows, block_co),
+                               lambda b, oc, t, ic: (b, n_dt - 1 - t, 0, oc)),
+        out_shape=jax.ShapeDtypeStruct((n, n_dt, rows, co), out_dtype),
         scratch_shapes=scratch,
         interpret=interpret,
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel",
-                                 "arbitrary", "arbitrary")),
+        compiler_params=compiler_params(
+            step, ("parallel", "parallel", "arbitrary", "arbitrary")),
     )(*operands)
+    return from_tiles(y, grid_hw)[:, :, :oh, :ow]
 
 
 def vmem_bytes(out_spatial, kernel, stride, block_ci, block_co,
@@ -244,38 +264,36 @@ def vmem_bytes(out_spatial, kernel, stride, block_ci, block_co,
     """Static per-grid-step VMEM footprint of ``conv_pallas_3d``.
 
     ``out_spatial`` is the conv OUTPUT extent per dim (the quantity the
-    leading-dim tiling counts); models the input slab, weights, output slab,
-    f32 accumulator + halo carry, and the tap-batched matmul output of the
-    widest phase.  Dilation widens the input slab and halo by the effective
-    kernel footprint.  The deconv backward's dx budget is this same model
-    with the channel roles swapped (see
-    ``kernels.deconv.kernel.vmem_bytes_bwd``).  ``w_dtype_bytes`` /
+    leading-dim tiling counts).  Models, in Mosaic's tiled layout
+    (``kernels.common.tile_bytes``), the double-buffered phase-split input,
+    weight and output blocks, the f32 accumulator + halo carry, and the
+    widest phase's tap-batched matmul result.  Dilation widens the
+    phase geometry through the effective kernel extent.  The deconv
+    backward's dx budget is this same model with the channel roles swapped
+    (see ``kernels.deconv.kernel.vmem_bytes_bwd``).  ``w_dtype_bytes`` /
     ``out_dtype_bytes`` default to ``in_dtype_bytes``; quantized plans pass
     1 for int8 operands.
     """
     w_dtype_bytes = in_dtype_bytes if w_dtype_bytes is None else w_dtype_bytes
     out_dtype_bytes = in_dtype_bytes if out_dtype_bytes is None \
         else out_dtype_bytes
-    dilation = tuple(dilation) if dilation is not None \
-        else (1,) * len(kernel)
-    k_eff = tuple((k - 1) * d + 1 for k, d in zip(kernel, dilation))
+    out_spatial, kernel, stride, dilation = lift_geometry3(
+        out_spatial, kernel, stride, dilation)
     m_max = phase_geometry(kernel, stride, dilation)
     halo = m_max[0] - 1
-    trail = tuple(out_spatial[1:])
     if dtile is None:
         dtile = out_spatial[0] + halo
-    in_trail = tuple((o - 1) * s + k
-                     for o, s, k in zip(trail, stride[1:], k_eff[1:]))
-    trail_elems = math.prod(trail)
-    in_elems = dtile * stride[0] * math.prod(in_trail)
-    out_elems = dtile * trail_elems
-    k_elems = math.prod(kernel)
-    taps_max = math.prod(m_max)
-    # widest per-phase gather of x (phase 0) and its batched matmul output
-    ph_elems = dtile * math.prod(-(-i // s)
-                                 for i, s in zip(in_trail, stride[1:]))
-    return (in_elems * block_ci * in_dtype_bytes                # input slab
-            + k_elems * block_ci * block_co * w_dtype_bytes     # weights
-            + out_elems * block_co * out_dtype_bytes            # output slab
-            + (dtile + 2 * halo) * trail_elems * block_co * 4   # acc + halo
-            + ph_elems * taps_max * block_co * 4)               # batched out
+    grid_hw = flat_grid(tuple(out_spatial[1:]), m_max[1:])
+    plane = grid_hw[0] * grid_hw[1]
+    rows = dtile * plane
+    margin = (m_max[1] - 1) * grid_hw[1] + m_max[2] - 1
+    taps = math.prod(m_max)
+    return (2 * math.prod(stride) * tile_bytes(rows, block_ci,
+                                               in_dtype_bytes)
+            + 2 * math.prod(kernel) * tile_bytes(block_ci, block_co,
+                                                 w_dtype_bytes)
+            + 2 * tile_bytes(rows, block_co, out_dtype_bytes)
+            + tile_bytes(margin + (dtile + halo) * plane, block_co, 4)
+            + (tile_bytes(halo * plane, block_co, 4) if halo else 0)
+            # the widest phase's tap-batched matmul result (f32)
+            + tile_bytes(rows, taps * block_co, 4))

@@ -38,8 +38,6 @@ from repro.kernels.conv import kernel as _ck
 from repro.kernels.deconv import kernel as _dk
 from repro.kernels.deconv import ops as _dops
 
-_default_interpret = _common.default_interpret
-
 
 def _lift_padding(pads, rank):
     """Lift per-dim (lo, hi) pairs onto the canonical 3D layout."""
@@ -118,8 +116,7 @@ def _conv_core(x3, w3, stride3, kernel3, block_ci, block_co, interpret,
 def _conv_fwd_impl(x, w, b, w_scale, stride, padding, dilation, groups,
                    activation, alpha, engine):
     cfg = engine.config
-    interpret = (cfg.interpret if cfg.interpret is not None
-                 else _default_interpret())
+    interpret = cfg.pallas_interpret
     rank = x.ndim - 2
     stride_r = _canon(stride, rank)
     pads_r = canon_padding(padding, rank)
@@ -197,8 +194,7 @@ def _bwd(stride, padding, dilation, groups, activation, alpha, engine,
     if w_scale is not None:
         wq, w = w, (w.astype(jnp.float32) * w_scale).astype(jnp.float32)
     cfg = engine.config
-    interpret = (cfg.interpret if cfg.interpret is not None
-                 else _default_interpret())
+    interpret = cfg.pallas_interpret
     rank = x.ndim - 2
     stride_r = _canon(stride, rank)
     pads_r = canon_padding(padding, rank)

@@ -21,15 +21,19 @@ Maps the paper's PE mesh onto the TPU memory hierarchy with a fused 4D grid
     one tile (K_d ≫ S_d·dtile) propagate correctly.  Each tile then owns a
     disjoint ``dtile·S_d``-row slab of the output: no HBM round-trip, no
     outside stitching.
+  * every block is a flattened 2-D slab: a tile's trailing spatial dims sit
+    zero-padded on an (Lh, Lw) grid, ``[dtile*Lh*Lw, C]``, the layout
+    Mosaic lowers without reshapes (``kernels.common``).
   * ONE tap-batched MXU matmul per phase: the phase's valid taps fold into
-    the weight columns, so x_flat [dtile*H*W, bci] contracts against
+    the weight columns, so the x slab [dtile*Lh*Lw, bci] contracts against
     [bci, n_taps*bco] in a single dispatch — S^d wide matmuls per grid step
     instead of K^d small ones (e.g. 27 -> 8 for 3³/s2, 25 -> 4 for 5²/s2).
     Taps across all phases still number exactly K^d — the IOM valid-MAC
     count; no inserted zero is ever touched.
   * the in-tile overlap-add (paper: FIFO-V/H exchange) is a shifted in-VMEM
-    accumulation into the per-phase buffer; phases interleave into the
-    output by a reshape/transpose at write-out.
+    accumulation into the per-phase buffer — one static row offset per tap
+    on the flattened grid; the kernel writes its output phase-major and
+    the phases interleave outside the kernel (one XLA transpose).
   * the TRAINING backward pass runs on the same uniform grid: deconv's
     adjoint is a strided convolution — which since PR 3 is the engine's
     first-class forward conv (``kernels.conv.kernel.conv_pallas_3d``).
@@ -43,6 +47,8 @@ Maps the paper's PE mesh onto the TPU memory hierarchy with a fused 4D grid
     loops statically collapse — the paper's "FIFO-D disabled"); ``ops.py``
     lifts 2D inputs as [N, H, 1, W, C] so the large image dim lands on the
     tileable leading axis.
+  * the dx body is the conv kernel's; dx and dw read dy split into its S^d
+    phases outside the kernel, so no kernel strides a value.
 
 The caller (``ops.py``) zero-pads the leading dim to ``n_dtiles * dtile``
 with at least ``ceil(K_d/S_d) - 1`` rows of slack, which makes the final
@@ -64,34 +70,48 @@ from jax.experimental.pallas import tpu as pltpu
 # Shared polyphase geometry (also served to kernels.conv); the old private
 # names are kept as aliases for in-repo callers.
 from repro.kernels.common import (  # noqa: F401
-    CompilerParams as _CompilerParams,
     apply_epilogue,
+    compiler_params,
+    flat_grid,
+    from_phases,
     halo_depth,
+    lift_geometry3,
+    mxu_dtype,
+    mxu_precision,
     phase_geometry as _phase_geometry,
     phase_major_tap_index,
     phase_taps as _phase_taps,
+    phase_weight_slab,
+    tile_bytes,
+    to_phases,
+    to_tiles,
 )
 
 
-def _deconv_kernel_body(*refs, tile_spatial, kernel, stride, dilation,
-                        out_trailing, n_ci_blocks, out_dtype,
+def _deconv_kernel_body(*refs, rows, plane, row_w, dtile, halo, kernel,
+                        stride, dilation, n_ci_blocks, out_dtype,
                         has_scale=False, has_bias=False,
                         activation="none", alpha=0.2):
     """One grid step: accumulate a (batch, co-block, d-tile, ci-block) part.
 
-    x_ref:   [1, dtile, H, W, bci]
-    w_ref:   [prod(K), bci, bco]                  (phase-major tap order)
-    s_ref:   [1, bco]                             (only when ``has_scale``)
-    b_ref:   [1, bco]                             (only when ``has_bias``)
-    o_ref:   [1, dtile*S_d, OH, OW, bco]          (this tile's output slab)
-    acc_ref: VMEM f32 [n_phases, dtile + M_d - 1, L_h, L_w, bco]
-    halo_ref: VMEM f32 [n_phases, M_d - 1, L_h, L_w, bco] (None if M_d == 1)
+    Every block is a flattened slab on the tile's (Lh, Lw) grid (see
+    ``kernels.common``), ``plane = Lh*Lw`` rows per leading-dim row:
 
-    Under dilation a tap ``m`` of phase ``p`` carries kernel element
-    ``k = (m*S + p)/dil``; phases no kernel element lands in are structural
-    zeros — their accumulator rows stay zero-initialised and interleave as
-    genuine zero output rows.  The fused epilogue runs at ``_flush`` on the
-    completed f32 accumulation (after the FIFO-D carry-in).
+    x_ref:   [1, 1, rows, bci]            (rows = dtile*plane; zero-padded)
+    w_ref:   [prod(K), bci, bco]          (phase-major tap order)
+    s_ref:   [1, bco]                     (only when ``has_scale``)
+    b_ref:   [1, bco]                     (only when ``has_bias``)
+    o_ref:   [1, 1, prod(S), rows, bco]   (phase-major; the host interleaves)
+    acc_ref: VMEM f32 [prod(S), (dtile+M_d-1)*plane + tail, bco]
+    halo_ref: VMEM f32 [prod(S), (M_d-1)*plane, bco] (None if M_d == 1)
+
+    Tap ``m`` of phase ``p`` overlap-adds ``x @ w[k]`` into the phase
+    accumulator at the single row offset ``m_d*plane + m_h*Lw + m_w``.
+    Under dilation a tap carries kernel element ``k = (m*S + p)/dil``;
+    phases no kernel element lands in are structural zeros — their
+    accumulator rows stay zero and come out as genuine zero output rows.
+    The fused epilogue runs at ``_flush`` on the completed f32 accumulation
+    (after the FIFO-D carry-in).
 
     Quantized operands (int8 x and/or w) ride the SAME matmuls: they are
     cast to f32 in-register right before the dot (|q| <= 127, so the cast
@@ -104,89 +124,71 @@ def _deconv_kernel_body(*refs, tile_spatial, kernel, stride, dilation,
     s_ref = next(it) if has_scale else None
     b_ref = next(it) if has_bias else None
     o_ref, acc_ref = next(it), next(it)
-    rest = list(it)
-    halo_ref = rest[0] if rest else None
-    quantized = (jnp.issubdtype(x_ref.dtype, jnp.integer)
-                 or jnp.issubdtype(w_ref.dtype, jnp.integer))
+    halo_ref = next(it, None)
     dt = pl.program_id(2)
     ci = pl.program_id(3)
-    m_max = _phase_geometry(kernel, stride, dilation)
-    halo = halo_depth(kernel, stride, dilation)
-    dtile = tile_spatial[0]
+    cdt = mxu_dtype(x_ref.dtype, w_ref.dtype)
+    bco = acc_ref.shape[-1]
 
     @pl.when(ci == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[0]                                    # [dtile, H, W, bci]
-    if quantized:
-        x = x.astype(jnp.float32)
-    dhw = math.prod(tile_spatial)
-    bci = x.shape[-1]
-    x_flat = x.reshape(dhw, bci)
-
+    x = x_ref[0, 0].astype(cdt)                     # [rows, bci]
     off = 0
-    for p_idx, p, taps in _phase_taps(kernel, stride, dilation):
+    for p_idx, _, taps in _phase_taps(kernel, stride, dilation):
         # Tap-batched MXU dispatch: the phase's valid taps sit contiguously
-        # in the phase-major weight layout, so ONE static slice feeds ONE
-        # contraction — x_flat [dhw, bci] against [n_taps, bci, bco] is a
-        # single [dhw, bci] @ [bci, n_taps*bco] matmul (S^d dispatches per
-        # grid step instead of K^d).  The column groups are then distributed
-        # into the shifted overlap-add slices (VPU adds, no MXU traffic).
-        w_taps = w_ref[off:off + len(taps)]         # [n_taps, bci, bco]
-        if quantized:
-            w_taps = w_taps.astype(jnp.float32)
+        # in the phase-major weight layout, so ONE [rows, bci] @
+        # [bci, n_taps*bco] matmul serves the whole phase (S^d dispatches
+        # per grid step instead of K^d).  The column groups are then
+        # distributed into the shifted overlap-add slices (VPU adds).
+        w = phase_weight_slab(w_ref, off, len(taps), cdt)
         off += len(taps)
-        contribs = jax.lax.dot_general(
-            x_flat, w_taps, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)     # [dhw, n_taps, bco]
+        res = jnp.dot(x, w, preferred_element_type=jnp.float32,
+                      precision=mxu_precision(cdt))
         for t_idx, m in enumerate(taps):
-            contrib = contribs[:, t_idx].reshape(*tile_spatial, -1)
-            # overlap-add: y_p[q] += x[q - m] * w_tap  ->  slice offset m
-            idx = (p_idx,) + tuple(slice(mj, mj + ij)
-                                   for mj, ij in zip(m, tile_spatial))
-            acc_ref[idx] += contrib
+            # overlap-add: y_p[q] += x[q - m] * w_tap  ->  row offset m
+            at = m[0] * plane + m[1] * row_w + m[2]
+            acc_ref[p_idx, pl.ds(at, rows), :] += \
+                res[:, t_idx * bco:(t_idx + 1) * bco]
 
+    last = ci == n_ci_blocks - 1
     if halo:
+        hp = halo * plane
+
         # FIFO-D exchange, in-grid: the previous tile's tail rows
         # overlap-add into the head of this tile's accumulator ...
-        @pl.when(jnp.logical_and(ci == n_ci_blocks - 1, dt > 0))
+        @pl.when(jnp.logical_and(last, dt > 0))
         def _carry_in():
-            acc_ref[:, :halo] += halo_ref[...]
+            acc_ref[:, pl.ds(0, hp), :] += halo_ref[...]
 
         # ... and this tile's tail (read AFTER the carry-in, so halos
         # deeper than one tile compose recursively) is left for the next.
-        @pl.when(ci == n_ci_blocks - 1)
+        @pl.when(last)
         def _carry_out():
-            halo_ref[...] = acc_ref[:, dtile:]
+            halo_ref[...] = acc_ref[:, pl.ds(dtile * plane, hp), :]
 
-    @pl.when(ci == n_ci_blocks - 1)
+    @pl.when(last)
     def _flush():
-        acc = acc_ref[:, :dtile]        # owned rows; the tail rides the halo
-        bco = acc.shape[-1]
-        lh, lw = acc.shape[2], acc.shape[3]
-        s_d, s_h, s_w = stride
-        # unflatten phases and interleave: out[q*S + p] = acc[p, q]
-        acc = acc.reshape(s_d, s_h, s_w, dtile, lh, lw, bco)
-        acc = acc.transpose(3, 0, 4, 1, 5, 2, 6)
-        full = acc.reshape(dtile * s_d, lh * s_h, lw * s_w, bco)
-        y = apply_epilogue(full[:, :out_trailing[0], :out_trailing[1]],
-                           b_ref[0] if b_ref is not None else None,
-                           activation, alpha,
-                           scale=s_ref[0] if s_ref is not None else None)
-        o_ref[0] = y.astype(out_dtype)
+        # owned rows only; the tail rides the halo
+        scale = s_ref[...] if s_ref is not None else None
+        bias = b_ref[...] if b_ref is not None else None
+        for p in range(acc_ref.shape[0]):
+            y = apply_epilogue(acc_ref[p, pl.ds(0, rows), :], bias,
+                               activation, alpha, scale=scale)
+            o_ref[0, 0, p] = y.astype(out_dtype)
 
 
 def deconv_pallas_3d(x: jax.Array, w_taps: jax.Array, *,
                      kernel: Sequence[int], stride: Sequence[int],
                      block_ci: int, block_co: int,
+                     interpret: bool,
                      dtile: int | None = None,
                      dilation: Sequence[int] | None = None,
                      groups: int = 1,
                      scale: jax.Array | None = None,
                      bias: jax.Array | None = None,
                      activation: str = "none", alpha: float = 0.2,
-                     interpret: bool = True,
                      out_dtype=None) -> jax.Array:
     """Uniform deconv on rank-3 canonical layout — one call, any input size.
 
@@ -202,6 +204,10 @@ def deconv_pallas_3d(x: jax.Array, w_taps: jax.Array, *,
     real output row lands inside the returned [N, D_pad*S_d, OH, OW, Co]
     extent and the last tile's halo carry-out is structurally zero.  Rows at
     or beyond (D-1)*S_d + K_d are zero and cropped by the caller.
+
+    The kernel works on flattened tiles and writes phase-major output; the
+    host-side flattening and the phase interleave are plain XLA reshapes
+    around the single ``pallas_call``.
     """
     n, d_pad, h, wdim, ci = x.shape
     co = w_taps.shape[-1]
@@ -227,33 +233,35 @@ def deconv_pallas_3d(x: jax.Array, w_taps: jax.Array, *,
 
     m_max = _phase_geometry(kernel, stride, dilation)
     halo = halo_depth(kernel, stride, dilation)
-    tile_spatial = (dtile, h, wdim)
-    lengths = tuple(i + m - 1 for i, m in zip(tile_spatial, m_max))
+    grid_hw = flat_grid((h, wdim), m_max[1:])
+    plane = grid_hw[0] * grid_hw[1]
+    rows = dtile * plane
+    tail = (m_max[1] - 1) * grid_hw[1] + m_max[2] - 1
     n_phases = math.prod(stride)
     out_trailing = tuple((i - 1) * s + k for i, s, k in
                          zip((h, wdim), stride[1:], k_eff[1:]))
-    out_block_lead = dtile * stride[0]
 
     body = functools.partial(
-        _deconv_kernel_body,
-        tile_spatial=tile_spatial, kernel=kernel, stride=stride,
-        dilation=dilation, out_trailing=out_trailing, n_ci_blocks=n_ci,
-        out_dtype=out_dtype, has_scale=scale is not None,
-        has_bias=bias is not None, activation=activation, alpha=alpha)
+        _deconv_kernel_body, rows=rows, plane=plane, row_w=grid_hw[1],
+        dtile=dtile, halo=halo, kernel=kernel, stride=stride,
+        dilation=dilation, n_ci_blocks=n_ci, out_dtype=out_dtype,
+        has_scale=scale is not None, has_bias=bias is not None,
+        activation=activation, alpha=alpha)
 
-    scratch = [pltpu.VMEM((n_phases, *lengths, block_co), jnp.float32)]
+    scratch = [pltpu.VMEM((n_phases, (dtile + halo) * plane + tail,
+                           block_co), jnp.float32)]
     if halo:
         scratch.append(
-            pltpu.VMEM((n_phases, halo, *lengths[1:], block_co), jnp.float32))
+            pltpu.VMEM((n_phases, halo * plane, block_co), jnp.float32))
 
     in_specs = [
-        pl.BlockSpec((1, dtile, h, wdim, block_ci),
-                     lambda b, oc, dt, ic: (b, dt, 0, 0,
+        pl.BlockSpec((1, 1, rows, block_ci),
+                     lambda b, oc, dt, ic: (b, dt, 0,
                                             (oc // nco_g) * n_ci + ic)),
         pl.BlockSpec((math.prod(kernel), block_ci, block_co),
                      lambda b, oc, dt, ic: (0, ic, oc)),
     ]
-    operands = [x, w_taps]
+    operands = [to_tiles(x, n_dt, grid_hw), w_taps]
     if scale is not None:
         in_specs.append(pl.BlockSpec((1, block_co),
                                      lambda b, oc, dt, ic: (0, oc)))
@@ -263,21 +271,26 @@ def deconv_pallas_3d(x: jax.Array, w_taps: jax.Array, *,
                                      lambda b, oc, dt, ic: (0, oc)))
         operands.append(bias.reshape(1, co))
 
-    grid = (n, n_co, n_dt, n_ci)
-    return pl.pallas_call(
+    step = vmem_bytes((d_pad, h, wdim), kernel, stride, block_ci, block_co,
+                      jnp.dtype(x.dtype).itemsize, dtile=dtile,
+                      dilation=dilation,
+                      w_dtype_bytes=jnp.dtype(w_taps.dtype).itemsize,
+                      out_dtype_bytes=jnp.dtype(out_dtype).itemsize)
+    y = pl.pallas_call(
         body,
-        grid=grid,
+        grid=(n, n_co, n_dt, n_ci),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, out_block_lead, *out_trailing, block_co),
+        out_specs=pl.BlockSpec((1, 1, n_phases, rows, block_co),
                                lambda b, oc, dt, ic: (b, dt, 0, 0, oc)),
-        out_shape=jax.ShapeDtypeStruct(
-            (n, n_dt * out_block_lead, *out_trailing, co), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((n, n_dt, n_phases, rows, co),
+                                       out_dtype),
         scratch_shapes=scratch,
         interpret=interpret,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel",
-                                 "arbitrary", "arbitrary")),
+        compiler_params=compiler_params(
+            step, ("parallel", "parallel", "arbitrary", "arbitrary")),
     )(*operands)
+    y = from_phases(y, stride, grid_hw)
+    return y[:, :, :out_trailing[0], :out_trailing[1]]
 
 
 def vmem_bytes(in_spatial, kernel, stride, block_ci, block_co,
@@ -286,43 +299,39 @@ def vmem_bytes(in_spatial, kernel, stride, block_ci, block_co,
                out_dtype_bytes: int | None = None) -> int:
     """Static VMEM footprint of one grid step (for the tiling planner).
 
-    ``dtile=None`` is the classic whole-leading-dim accounting; with
-    ``dtile`` set it accounts the tiled grid's per-step input/output blocks
-    plus the f32 halo-carry scratch.  Dilation widens the accumulator and
-    output footprints by the effective kernel extent.  ``w_dtype_bytes`` /
-    ``out_dtype_bytes`` default to ``in_dtype_bytes`` (the historical
-    single-width model); quantized plans pass 1 for int8 operands.
+    Counts what ``deconv_pallas_3d`` allocates in Mosaic's tiled layout
+    (``kernels.common.tile_bytes``: lanes pad to 128, so thin channel
+    blocks pay for full lanes): the double-buffered input, weight and
+    phase-major output blocks, the f32 accumulator and halo carry, and the
+    widest phase's tap-batched matmul result.  ``dtile=None`` is one
+    tile over the whole leading dim plus its halo slack.  Dilation widens
+    the phase geometry through the effective kernel extent.
+    ``w_dtype_bytes`` / ``out_dtype_bytes`` default to ``in_dtype_bytes``;
+    quantized plans pass 1 for int8 operands.
     """
     w_dtype_bytes = in_dtype_bytes if w_dtype_bytes is None else w_dtype_bytes
     out_dtype_bytes = in_dtype_bytes if out_dtype_bytes is None \
         else out_dtype_bytes
-    dilation = tuple(dilation) if dilation is not None \
-        else (1,) * len(kernel)
-    k_eff = tuple((k - 1) * d + 1 for k, d in zip(kernel, dilation))
+    in_spatial, kernel, stride, dilation = lift_geometry3(
+        in_spatial, kernel, stride, dilation)
     m_max = _phase_geometry(kernel, stride, dilation)
+    halo = m_max[0] - 1
     if dtile is None:
-        lengths = tuple(i + m - 1 for i, m in zip(in_spatial, m_max))
-        out_spatial = tuple((i - 1) * s + k
-                            for i, s, k in zip(in_spatial, stride, k_eff))
-        in_elems = math.prod(in_spatial)
-        halo_elems = 0
-    else:
-        trail = tuple(in_spatial[1:])
-        lengths = (dtile + m_max[0] - 1,) + tuple(
-            i + m - 1 for i, m in zip(trail, m_max[1:]))
-        out_spatial = (dtile * stride[0],) + tuple(
-            (i - 1) * s + k
-            for i, s, k in zip(trail, stride[1:], k_eff[1:]))
-        in_elems = dtile * math.prod(trail)
-        halo_elems = (math.prod(stride) * (m_max[0] - 1)
-                      * math.prod(lengths[1:]))
-    return (in_elems * block_ci * in_dtype_bytes
-            + math.prod(kernel) * block_ci * block_co * w_dtype_bytes
-            + math.prod(out_spatial) * block_co * out_dtype_bytes
-            + (math.prod(stride) * math.prod(lengths) + halo_elems)
-            * block_co * 4
-            # tap-batched matmul output of the widest phase (f32, pre-split)
-            + in_elems * math.prod(m_max) * block_co * 4)
+        dtile = in_spatial[0] + halo
+    grid_hw = flat_grid(tuple(in_spatial[1:]), m_max[1:])
+    plane = grid_hw[0] * grid_hw[1]
+    rows = dtile * plane
+    tail = (m_max[1] - 1) * grid_hw[1] + m_max[2] - 1
+    n_ph = math.prod(stride)
+    taps = math.prod(m_max)
+    return (2 * tile_bytes(rows, block_ci, in_dtype_bytes)
+            + 2 * math.prod(kernel) * tile_bytes(block_ci, block_co,
+                                                 w_dtype_bytes)
+            + 2 * n_ph * tile_bytes(rows, block_co, out_dtype_bytes)
+            + n_ph * tile_bytes((dtile + halo) * plane + tail, block_co, 4)
+            + (n_ph * tile_bytes(halo * plane, block_co, 4) if halo else 0)
+            # the widest phase's tap-batched matmul result (f32)
+            + tile_bytes(rows, taps * block_co, 4))
 
 
 # -- Backward (VJP) kernels: the adjoint on the SAME fused 4D grid -----------
@@ -330,9 +339,9 @@ def vmem_bytes(in_spatial, kernel, stride, block_ci, block_co,
 def deconv_dx_pallas_3d(dy: jax.Array, w: jax.Array, *,
                         kernel: Sequence[int], stride: Sequence[int],
                         block_ci: int, block_co: int, dtile: int,
+                        interpret: bool,
                         dilation: Sequence[int] | None = None,
                         groups: int = 1,
-                        interpret: bool = True,
                         out_dtype=None) -> jax.Array:
     """dx on the uniform grid: one ``pallas_call``, any dy size.
 
@@ -362,90 +371,81 @@ def deconv_dx_pallas_3d(dy: jax.Array, w: jax.Array, *,
         interpret=interpret, out_dtype=out_dtype or dy.dtype)
 
 
-def _deconv_dw_kernel_body(x_ref, dy_ref, o_ref, acc_ref, xcarry_ref=None, *,
-                           tile_spatial, kernel, stride, dilation,
-                           n_batch, n_dtiles, out_dtype):
+def _deconv_dw_kernel_body(x_ref, dy_ref, o_ref, acc_ref, xext_ref, *,
+                           rows, plane, row_w, margin, dtile, halo, kernel,
+                           stride, dilation, n_batch, n_dtiles, out_dtype):
     """One grid step of dw: per-tap [bci, bco] contractions into VMEM.
 
     dw[k, ci, co] = sum_{n, i} x[n, i, ci] * dy[n, i*S+k, co] — for each tap
     the contraction runs over the whole (batch, spatial) extent, so it
     accumulates across the sequential (N, d-tile) grid dims into an f32 VMEM
-    scratch and flushes once at the last step.  Cross-tile pairs (x tail
-    rows against the next dy block's head) ride a carried copy of the last
-    M_d - 1 x rows — iteration stays forward, no second pass.
+    scratch and flushes once at the last step.  In phase terms tap ``m`` of
+    phase ``p`` pairs ``x[u - m]`` with ``dy_p[u]``: on the flattened tile
+    grid that is ONE row offset into an f32 copy of x (``xext_ref``), whose
+    head holds a zero margin and the previous tile's last M_d - 1 rows —
+    cross-tile pairs never leave VMEM and iteration stays forward.
 
-    A phase's valid taps form a cross product (leading shifts) x (trailing
-    shifts), so the whole phase is ONE MXU dispatch: stacked x windows
-    against stacked dy windows contract into every per-tap [bci, bco] block
-    at once — S^d dispatches per grid step here too, not K^d.  The scratch
-    is laid out tap-flat in the same phase-major order as the weights
-    (contiguous per-phase runs); the caller unscrambles.
+    The whole phase is ONE MXU dispatch: its shifted x windows sit side by
+    side as a [rows, n_taps*bci] operand and contract against the phase's dy
+    slab into every per-tap [bci, bco] block at once — S^d dispatches per
+    grid step here too, not K^d.  The scratch is laid out tap-flat in the
+    same phase-major order as the weights (contiguous per-phase runs); the
+    caller unscrambles.
 
-    x_ref:   [1, dtile, H, W, bci]
-    dy_ref:  [1, dtile*S_d, OH, OW, bco]
-    o_ref:   [prod(K), bci, bco]           (phase-major tap order)
-    acc_ref: VMEM f32 [prod(K), bci, bco]
-    xcarry_ref: VMEM f32 [M_d - 1, H, W, bci] (None if M_d == 1)
+    x_ref:    [1, 1, rows, bci]             (zero-padded flattened tile)
+    dy_ref:   [1, 1, prod(S), rows, bco]    (host-split output phases)
+    o_ref:    [prod(K), bci, bco]           (phase-major tap order)
+    acc_ref:  VMEM f32 [prod(K)*bci, bco]
+    xext_ref: VMEM f32 [margin + (M_d-1)*plane + rows, bci]
     """
     b = pl.program_id(2)
     t = pl.program_id(3)
-    m_max = _phase_geometry(kernel, stride, dilation)
-    halo = halo_depth(kernel, stride, dilation)
-    dtile, h, wdim = tile_spatial
+    bci = xext_ref.shape[-1]
+    base = margin + halo * plane
+    cdt = mxu_dtype(x_ref.dtype, dy_ref.dtype)
 
     @pl.when(jnp.logical_and(b == 0, t == 0))
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[0].astype(jnp.float32)                # [dtile, H, W, bci]
-    if halo:
+    if base:
         @pl.when(t == 0)
-        def _zero_carry():
-            xcarry_ref[...] = jnp.zeros_like(xcarry_ref)
-        # x rows [t*dtile - (M_d-1), (t+1)*dtile): carried head + this tile
-        x_ext = jnp.concatenate([xcarry_ref[...], x], axis=0)
-    else:
-        x_ext = x
-    bci = x.shape[-1]
-    dy = dy_ref[0]                                  # [dtile*S_d, OH, OW, bco]
-    bco = dy.shape[-1]
+        def _zero_head():
+            xext_ref[pl.ds(0, base), :] = jnp.zeros((base, bci), jnp.float32)
+
+    xext_ref[pl.ds(base, rows), :] = x_ref[0, 0].astype(jnp.float32)
 
     off = 0
-    for _, p, taps in _phase_taps(kernel, stride, dilation):
-        dy_ph = dy[tuple(slice(pj, None, sj) for pj, sj in zip(p, stride))]
-        # the phase's taps are a (leading m_d) x (trailing m_h, m_w) grid
-        lead = sorted({m[0] for m in taps})
-        trail = [m[1:] for m in taps if m[0] == lead[0]]
-        assert len(taps) == len(lead) * len(trail)
-        # x[u - m_d] pairs with dy phase row u: leading shifts window x_ext,
-        # trailing shifts window dy_ph
-        xs = jnp.stack([x_ext[m_max[0] - 1 - md:m_max[0] - 1 - md + dtile]
-                        for md in lead])            # [G, dtile, H, W, bci]
-        dys = jnp.stack([dy_ph[:, mh:mh + h, mw:mw + wdim]
-                         for mh, mw in trail])      # [T, dtile, H, W, bco]
+    for p_idx, _, taps in _phase_taps(kernel, stride, dilation):
+        dy = dy_ref[0, 0, p_idx].astype(jnp.float32)    # [rows, bco]
+        wins = [xext_ref[pl.ds(base - (m[0] * plane + m[1] * row_w + m[2]),
+                               rows), :] for m in taps]
+        xs = wins[0] if len(wins) == 1 else jnp.concatenate(wins, axis=1)
         res = jax.lax.dot_general(
-            xs.reshape(len(lead), -1, bci), dys.reshape(len(trail), -1, bco),
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)     # [G, bci, T, bco]
-        res = res.transpose(0, 2, 1, 3).reshape(len(taps), bci, bco)
-        acc_ref[off:off + len(taps)] += res
+            xs, dy, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=mxu_precision(cdt))           # [n_taps*bci, bco]
+        acc_ref[pl.ds(off * bci, len(taps) * bci), :] += res
         off += len(taps)
 
     if halo:
         # recursive like the forward halo: composes when dtile < M_d - 1
-        xcarry_ref[...] = x_ext[dtile:]
+        hp = halo * plane
+        xext_ref[pl.ds(margin, hp), :] = \
+            xext_ref[pl.ds(margin + dtile * plane, hp), :]
 
     @pl.when(jnp.logical_and(b == n_batch - 1, t == n_dtiles - 1))
     def _flush():
-        o_ref[...] = acc_ref[...].astype(out_dtype)
+        for k in range(o_ref.shape[0]):
+            o_ref[k] = acc_ref[pl.ds(k * bci, bci), :].astype(out_dtype)
 
 
 def deconv_dw_pallas_3d(x: jax.Array, dy: jax.Array, *,
                         kernel: Sequence[int], stride: Sequence[int],
                         block_ci: int, block_co: int, dtile: int,
+                        interpret: bool,
                         dilation: Sequence[int] | None = None,
                         groups: int = 1,
-                        interpret: bool = True,
                         out_dtype=None) -> jax.Array:
     """dw on the uniform grid: one ``pallas_call`` reducing over (N, tiles).
 
@@ -467,7 +467,6 @@ def deconv_dw_pallas_3d(x: jax.Array, dy: jax.Array, *,
     assert d_pad % dtile == 0, (d_pad, dtile)
     n_dt = d_pad // dtile
     assert dy.shape[1] == d_pad * stride[0], (dy.shape, d_pad, stride)
-    oh, ow = dy.shape[2], dy.shape[3]
     assert ci % groups == 0 and co % groups == 0, (ci, co, groups)
     cig = ci // groups
     assert cig % block_ci == 0 and co % block_co == 0, (ci, co,
@@ -475,27 +474,33 @@ def deconv_dw_pallas_3d(x: jax.Array, dy: jax.Array, *,
     n_ci, n_co = cig // block_ci, co // block_co
     assert n_co % groups == 0, (n_co, groups)
     nco_g = n_co // groups
+    m_max = _phase_geometry(kernel, stride, dilation)
     halo = halo_depth(kernel, stride, dilation)
-    tile_spatial = (dtile, h, wdim)
+    grid_hw = flat_grid((h, wdim), m_max[1:])
+    plane = grid_hw[0] * grid_hw[1]
+    rows = dtile * plane
+    margin = (m_max[1] - 1) * grid_hw[1] + m_max[2] - 1
+    n_taps = math.prod(kernel)
+    n_phases = math.prod(stride)
 
     body = functools.partial(
-        _deconv_dw_kernel_body, tile_spatial=tile_spatial, kernel=kernel,
-        stride=stride, dilation=dilation, n_batch=n, n_dtiles=n_dt,
-        out_dtype=out_dtype)
-    n_taps = math.prod(kernel)
-    scratch = [pltpu.VMEM((n_taps, block_ci, block_co), jnp.float32)]
-    if halo:
-        scratch.append(pltpu.VMEM((halo, h, wdim, block_ci), jnp.float32))
-
-    grid = (n_ci, n_co, n, n_dt)
+        _deconv_dw_kernel_body, rows=rows, plane=plane, row_w=grid_hw[1],
+        margin=margin, dtile=dtile, halo=halo, kernel=kernel, stride=stride,
+        dilation=dilation, n_batch=n, n_dtiles=n_dt, out_dtype=out_dtype)
+    scratch = [pltpu.VMEM((n_taps * block_ci, block_co), jnp.float32),
+               pltpu.VMEM((margin + halo * plane + rows, block_ci),
+                          jnp.float32)]
+    step = vmem_bytes_dw((d_pad, h, wdim), kernel, stride, block_ci,
+                         block_co, jnp.dtype(x.dtype).itemsize, dtile=dtile,
+                         dilation=dilation)
     return pl.pallas_call(
         body,
-        grid=grid,
+        grid=(n_ci, n_co, n, n_dt),
         in_specs=[
-            pl.BlockSpec((1, dtile, h, wdim, block_ci),
-                         lambda ic, oc, b, t: (b, t, 0, 0,
+            pl.BlockSpec((1, 1, rows, block_ci),
+                         lambda ic, oc, b, t: (b, t, 0,
                                                (oc // nco_g) * n_ci + ic)),
-            pl.BlockSpec((1, dtile * stride[0], oh, ow, block_co),
+            pl.BlockSpec((1, 1, n_phases, rows, block_co),
                          lambda ic, oc, b, t: (b, t, 0, 0, oc)),
         ],
         out_specs=pl.BlockSpec((n_taps, block_ci, block_co),
@@ -503,10 +508,9 @@ def deconv_dw_pallas_3d(x: jax.Array, dy: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((n_taps, cig, co), out_dtype),
         scratch_shapes=scratch,
         interpret=interpret,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel",
-                                 "arbitrary", "arbitrary")),
-    )(x, dy)
+        compiler_params=compiler_params(
+            step, ("parallel", "parallel", "arbitrary", "arbitrary")),
+    )(to_tiles(x, n_dt, grid_hw), to_phases(dy, stride, n_dt, grid_hw))
 
 
 def vmem_bytes_dx(in_spatial, kernel, stride, block_ci, block_co,
@@ -530,30 +534,33 @@ def vmem_bytes_dw(in_spatial, kernel, stride, block_ci, block_co,
                   dilation=None) -> int:
     """Static per-grid-step VMEM footprint of the dw VJP kernel.
 
-    Models the x slab + dy slab + f32 dw scratch + the f32 x_ext/carry and
-    the stacked per-phase window batches of the widest phase.
+    Models, in Mosaic's tiled layout, the double-buffered x tile, dy phase
+    slab and dw output block, the f32 dw scratch and the f32 x copy with
+    its margin and carry, and the widest phase's window batch, f32 dy and
+    contraction result.
     """
-    dilation = tuple(dilation) if dilation is not None \
-        else (1,) * len(kernel)
-    k_eff = tuple((k - 1) * d + 1 for k, d in zip(kernel, dilation))
+    in_spatial, kernel, stride, dilation = lift_geometry3(
+        in_spatial, kernel, stride, dilation)
     m_max = _phase_geometry(kernel, stride, dilation)
     halo = m_max[0] - 1
-    trail = tuple(in_spatial[1:])
     if dtile is None:
         dtile = in_spatial[0] + halo
-    out_trail = tuple((i - 1) * s + k
-                      for i, s, k in zip(trail, stride[1:], k_eff[1:]))
-    trail_elems = math.prod(trail)
-    dy_elems = dtile * stride[0] * math.prod(out_trail)
-    x_elems = dtile * trail_elems
+    grid_hw = flat_grid(tuple(in_spatial[1:]), m_max[1:])
+    plane = grid_hw[0] * grid_hw[1]
+    rows = dtile * plane
+    margin = (m_max[1] - 1) * grid_hw[1] + m_max[2] - 1
     k_elems = math.prod(kernel)
-    return (x_elems * block_ci * in_dtype_bytes                # x slab
-            + dy_elems * block_co * in_dtype_bytes             # dy slab
-            + k_elems * block_ci * block_co * (in_dtype_bytes + 4)
-            + (dtile + 2 * halo) * trail_elems * block_ci * 4  # x_ext+c
-            # stacked per-phase window batches (widest phase, f32)
-            + x_elems * (m_max[0] * block_ci
-                         + math.prod(m_max[1:]) * block_co) * 4)
+    taps = math.prod(m_max)
+    return (2 * tile_bytes(rows, block_ci, in_dtype_bytes)
+            + 2 * math.prod(stride) * tile_bytes(rows, block_co,
+                                                 in_dtype_bytes)
+            + 2 * k_elems * tile_bytes(block_ci, block_co, in_dtype_bytes)
+            + tile_bytes(k_elems * block_ci, block_co, 4)
+            + tile_bytes(margin + (halo + dtile) * plane, block_ci, 4)
+            # the widest phase: x windows, f32 dy and the contraction
+            + tile_bytes(rows, taps * block_ci, 4)
+            + tile_bytes(rows, block_co, 4)
+            + tile_bytes(taps * block_ci, block_co, 4))
 
 
 def vmem_bytes_bwd(in_spatial, kernel, stride, block_ci, block_co,

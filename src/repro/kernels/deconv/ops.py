@@ -39,7 +39,6 @@ from repro.kernels.deconv import kernel as _k
 # host-side canonicalisation shared with kernels.conv.ops
 _pad_axis_to = _common.pad_axis_to
 _lift_3d = _common.lift_3d
-_default_interpret = _common.default_interpret
 
 
 def _phase_major(w3, kernel3, stride3, dilation3=None):
@@ -99,16 +98,10 @@ def _core_call(x3, w3, stride3, kernel3, block_ci, block_co, interpret,
     return _common.crop_group_axis(y[:, :out3[0]], -1, groups, cog)
 
 
-def _resolve(engine):
-    cfg = engine.config
-    interpret = (cfg.interpret if cfg.interpret is not None
-                 else _default_interpret())
-    return cfg, interpret
-
-
 def _deconv_fwd_impl(x, w, b, w_scale, stride, padding, dilation, groups,
                      activation, alpha, engine):
-    cfg, interpret = _resolve(engine)
+    cfg = engine.config
+    interpret = cfg.pallas_interpret
     rank = x.ndim - 2
     stride_r = _canon(stride, rank)
     pads_r = canon_padding(padding, rank)
@@ -225,7 +218,7 @@ def _bwd(stride, padding, dilation, groups, activation, alpha, engine,
             "train with Precision(act_quant='none')")
     if w_scale is not None:
         wq, w = w, (w.astype(jnp.float32) * w_scale).astype(jnp.float32)
-    _, interpret = _resolve(engine)
+    interpret = engine.config.pallas_interpret
     rank = x.ndim - 2
     stride_r = _canon(stride, rank)
     pads_r = canon_padding(padding, rank)

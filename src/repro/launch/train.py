@@ -57,12 +57,14 @@ def main():
     from repro.configs import get_config
     from repro.data import DcnnBatches, TokenBatches, VolumeBatches
     from repro.launch import steps as ST
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_host_mesh
     from repro.models import dcnn as D
     from repro.optim import AdamWConfig, adamw_init
     from repro.runtime import Trainer, TrainLoopConfig
     from repro.runtime.dp_trainer import record_dp_metrics
 
+    print(f"compile cache: {enable_compile_cache()}")
     telemetry = (obs.Telemetry.create(jsonl_path=args.telemetry)
                  if args.telemetry else None)
     cfg = get_config(args.arch)

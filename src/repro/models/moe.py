@@ -213,12 +213,8 @@ def moe_shardmap(p: MoeParams, x: jax.Array, cfg: ModelConfig):
         body = lambda xl, wr, wi_, wo_: local(xl, wr, wi_, None, wo_)
         args = (x, p.w_router, wi, wo)
         specs_in = (dp, P(), wspec, wspec)
-    try:
-        fn = compat.shard_map(body, mesh=mesh, in_specs=specs_in,
-                           out_specs=(dp, P()), check_vma=False)
-    except TypeError:
-        fn = compat.shard_map(body, mesh=mesh, in_specs=specs_in,
-                           out_specs=(dp, P()), check_rep=False)
+    fn = compat.shard_map_norep(body, mesh=mesh, in_specs=specs_in,
+                                out_specs=(dp, P()))
     out, aux = fn(*args)
     out = checkpoint_name(out, "blk_out")
     return out, aux

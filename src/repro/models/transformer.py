@@ -533,8 +533,6 @@ def _xent_vocab_parallel(mesh, cfg, hf, lf, table, chunk):
             idx = jnp.clip(lc - v0, 0, v_loc - 1)
             ll_part = jnp.take_along_axis(logits, idx[:, None], axis=-1)[:, 0]
             ll_full = jax.lax.psum(jnp.where(mine, ll_part, 0.0), "model")
-            # rank-1 carry, NOT scalar: jax 0.4.37's shard_map partial-eval
-            # mis-names scalar scan carries under grad (_SpecError)
             return acc + jnp.sum(lse - ll_full, keepdims=True), None
 
         acc, _ = maybe_scan(jax.checkpoint(body),
@@ -544,14 +542,9 @@ def _xent_vocab_parallel(mesh, cfg, hf, lf, table, chunk):
         return acc
 
     dp = P(batch_axes if batch_axes else None, None)
-    try:
-        fn = compat.shard_map(local, mesh=mesh,
-                           in_specs=(dp, P(dp[0]), P("model", None)),
-                           out_specs=P(None), check_vma=False)
-    except TypeError:
-        fn = compat.shard_map(local, mesh=mesh,
-                           in_specs=(dp, P(dp[0]), P("model", None)),
-                           out_specs=P(None), check_rep=False)
+    fn = compat.shard_map_norep(local, mesh=mesh,
+                                in_specs=(dp, P(dp[0]), P("model", None)),
+                                out_specs=P(None))
     return fn(hf, lf, table.astype(hf.dtype))[0] / t
 
 

@@ -1,68 +1,32 @@
-"""Version-compat shims for JAX API drift.
+"""Thin wrappers over the installed JAX's sharding APIs.
 
-``jax.sharding.AxisType`` (and ``jax.make_mesh(..., axis_types=...)``)
-appeared after JAX 0.4.37; ``make_mesh`` here passes ``axis_types`` only
-when the installed JAX supports it, so call sites stay uniform across
-versions instead of sprinkling hasattr checks.
+One spelling per concept for the call sites: ``shard_map_norep`` (every
+explicit-SPMD region here turns the replication checker off),
+``make_mesh`` (Auto or Explicit axis types) and ``cost_analysis_dict``.
 """
 
 from __future__ import annotations
 
 import jax
 
-# jax.shard_map was promoted out of jax.experimental after 0.4.x; alias the
-# one the installed JAX has.  Call sites keep their own check_vma/check_rep
-# TypeError fallback (that kwarg renamed independently).
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
 
 def shard_map_norep(f, *, mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking off, across the kwarg rename:
-    ``check_vma`` (newer JAX) vs ``check_rep`` (0.4.x).  Every explicit-SPMD
+    """``jax.shard_map`` with replication checking off.  Every explicit-SPMD
     region in this repo (dp trainer, vocab-parallel CE, the mesh-aware
     compiled schedules) wants the check off — int8 collectives and Pallas
     bodies confuse the replication checker."""
-    try:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
-    except TypeError:  # jax 0.4.x spells it check_rep
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-
-
-def supports_axis_types() -> bool:
-    return hasattr(jax.sharding, "AxisType")
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a flat dict on every JAX.
-
-    JAX 0.4.x returns a per-device list of dicts; newer JAX returns one
-    flat dict.  Returns {} when analysis is unavailable (some backends).
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
+    """``compiled.cost_analysis()``, or {} where the backend has none."""
+    return compiled.cost_analysis() or {}
 
 
 def make_mesh(axis_shapes, axis_names, *, explicit: bool = False):
-    """``jax.make_mesh`` that degrades gracefully without ``AxisType``.
-
-    ``explicit=False`` (the default, Auto axes) is representable on every
-    supported JAX — older versions simply have no axis_types concept and
-    behave as Auto.  ``explicit=True`` requires real AxisType support.
-    """
-    kw = {}
-    if supports_axis_types():
-        at = (jax.sharding.AxisType.Explicit if explicit
-              else jax.sharding.AxisType.Auto)
-        kw["axis_types"] = (at,) * len(axis_names)
-    elif explicit:
-        raise NotImplementedError(
-            "explicit-sharding meshes need jax.sharding.AxisType "
-            f"(installed jax {jax.__version__} predates it)")
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kw)
+    """``jax.make_mesh`` with every axis Auto (default) or Explicit."""
+    at = (jax.sharding.AxisType.Explicit if explicit
+          else jax.sharding.AxisType.Auto)
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(at,) * len(axis_names))

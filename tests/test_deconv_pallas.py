@@ -177,11 +177,12 @@ def test_planner_respects_budget_and_explicit_blocks():
 def test_block_choice_respects_vmem():
     """The old choose_blocks behaviour (channels-only shrink) is the
     planner's allow_split=False mode — one entry point, one VMEM model."""
-    plan = plan_uniform_tiles((16, 16, 16), (3, 3, 3), (2, 2, 2), 256, 256,
-                              vmem_budget=4 << 20, allow_split=False)
+    plan = plan_uniform_tiles((4, 4, 4), (3, 3, 3), (2, 2, 2), 256, 256,
+                              vmem_budget=2 << 20, allow_split=False)
     bci, bco = plan.block_ci, plan.block_co
     assert plan.n_dtiles == 1
-    assert vmem_bytes((16, 16, 16), (3, 3, 3), (2, 2, 2), bci, bco) <= 4 << 20
+    assert (bci, bco) != (128, 128)          # the channels had to shrink
+    assert vmem_bytes((4, 4, 4), (3, 3, 3), (2, 2, 2), bci, bco) <= 2 << 20
     assert bci >= 8 and bco >= 8
 
 

@@ -127,7 +127,7 @@ def test_deconv_grads_match_lax(rng, rank, stride, dil, groups):
 def test_planner_blocks_channels_per_group():
     """Grouped plans tile the PER-GROUP channel extents and still respect
     the VMEM budget the caller set."""
-    budget = 256 * 1024
+    budget = 1024 * 1024
     for groups in (2, 4):
         plan = plan_uniform_tiles((16, 16), (3, 3), (2, 2), 128, 256,
                                   groups=groups, vmem_budget=budget)
@@ -146,7 +146,11 @@ def test_dilated_plan_budgets_effective_kernel():
     """A dilated kernel's halo is (K-1)*d deep — the plan's working set
     must reflect the EFFECTIVE kernel, so the dilated plan can never be
     cheaper than the dense one at the same geometry."""
-    dense = plan_uniform_tiles((32, 32), (3, 3), (2, 2), 64, 64)
+    # a budget both fit whole, so the two plans tile identically
+    budget = 64 << 20
+    dense = plan_uniform_tiles((32, 32), (3, 3), (2, 2), 64, 64,
+                               vmem_budget=budget)
     dil = plan_uniform_tiles((32, 32), (3, 3), (2, 2), 64, 64,
-                             dilation=(2, 2))
+                             dilation=(2, 2), vmem_budget=budget)
+    assert dense.n_dtiles == dil.n_dtiles == 1
     assert dil.step_vmem_bytes >= dense.step_vmem_bytes

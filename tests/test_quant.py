@@ -42,6 +42,7 @@ from repro.core import (
 from repro.core import networks, tiling
 from repro.core.jaxpr_utils import count_prims
 from repro.core.networks import Epilogue, UniformLayer, deconv_stack
+from repro.kernels.common import tile_bytes
 from repro.kernels.deconv.kernel import vmem_bytes as deconv_vmem_bytes
 
 ENGINE = UniformEngine(EngineConfig(method="pallas"))
@@ -201,9 +202,11 @@ def test_byte_model_charges_int8_weight_width():
     p8 = tiling.plan_uniform_tiles(sp, k, s, 64, 64, mode="deconv",
                                    w_dtype_bytes=1)
     # same blocks -> the delta is EXACTLY the weight slab's saved bytes
+    # (double-buffered, in the tiled VMEM layout)
     assert (p16.dtile, p16.block_ci, p16.block_co) == \
         (p8.dtile, p8.block_ci, p8.block_co)
-    saved = 3 * 1 * 3 * p16.block_ci * p16.block_co * (2 - 1)
+    saved = 2 * 3 * 1 * 3 * (tile_bytes(p16.block_ci, p16.block_co, 2)
+                             - tile_bytes(p16.block_ci, p16.block_co, 1))
     assert p16.step_vmem_bytes - p8.step_vmem_bytes == saved
     # dispatch counts are a function of blocks/grid only — identical
     t16 = tiling.plan_cost_terms(p16, sp, k, s, 64, 64, mode="deconv",
@@ -227,16 +230,20 @@ def test_weight_heavy_step_bytes_roughly_halve():
 def test_strict_vmem_accepts_quantized_plan():
     sp, k, s = (4, 1, 4), (3, 1, 3), (2, 1, 2)
     ci = co = 256
+    blk = 128           # MXU-wide blocks: below ~32 rows int8 tiles pad
     # the minimal feasible working set at each width (budget 1 forces the
     # planner to its smallest plan, returned best-effort)
     lo8 = tiling.plan_uniform_tiles(sp, k, s, ci, co, mode="deconv",
-                                    vmem_budget=1, w_dtype_bytes=1)
+                                    vmem_budget=1, w_dtype_bytes=1,
+                                    block_ci=blk, block_co=blk)
     lo16 = tiling.plan_uniform_tiles(sp, k, s, ci, co, mode="deconv",
-                                     vmem_budget=1)
+                                     vmem_budget=1, block_ci=blk,
+                                     block_co=blk)
     assert lo8.step_vmem_bytes < lo16.step_vmem_bytes
     budget = (lo8.step_vmem_bytes + lo16.step_vmem_bytes) // 2
     eng = UniformEngine(EngineConfig(method="pallas", strict_vmem=True,
-                                     max_tile_bytes=budget))
+                                     max_tile_bytes=budget, block_ci=blk,
+                                     block_co=blk))
     # int8 weights fit the budget ...
     plan = eng.plan("deconv", sp, k, s, ci, co, w_dtype_bytes=1)
     assert not plan.overflows
@@ -364,10 +371,12 @@ def test_compress_dedups_onto_quant(rng):
 # ---------------------------------------------------------------------------
 
 def _q8_chain(rng):
-    layers = deconv_stack("g", 2, 4, [8, 8, 4])
+    # channels wide enough that int8 weight tiles (32 rows) are denser
+    # than 16-bit ones in the tiled VMEM layout
+    layers = deconv_stack("g", 2, 4, [64, 64, 4])
     ws = init_network_weights(layers, jax.random.PRNGKey(0))
     wq = quant.quantize_weights(ws, Precision(weight_quant="int8"))
-    x = jnp.asarray(rng.randn(1, 4, 4, 8), jnp.float32)
+    x = jnp.asarray(rng.randn(1, 4, 4, 64), jnp.float32)
     return layers, ws, wq, x
 
 

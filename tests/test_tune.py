@@ -59,7 +59,7 @@ def _chain():
 
 class TestCandidateSpace:
     def test_every_candidate_fits_budget(self):
-        budget = 64 * 1024
+        budget = 1024 * 1024
         cands = tune.candidate_plans(GEOM3, vmem_budget=budget)
         assert cands
         for p in cands:
@@ -78,7 +78,7 @@ class TestCandidateSpace:
 
     def test_strict_vmem_engine_accepts_every_candidate(self):
         """Any tuned winner passes EngineConfig(strict_vmem=True)."""
-        budget = 64 * 1024
+        budget = 1024 * 1024
         for p in tune.candidate_plans(GEOM3, vmem_budget=budget):
             cache = tune.TunedPlanCache()
             cache.put(GEOM3.key_tuple, p)
@@ -273,7 +273,7 @@ class TestEngineIntegration:
                                     vmem_budget=1 << 30)
         cache.put(GEOM.key_tuple, big)
         eng = UniformEngine(EngineConfig(method="pallas",
-                                         max_tile_bytes=64 * 1024,
+                                         max_tile_bytes=256 * 1024,
                                          tuned_plans=cache))
         plan = eng.plan(GEOM.mode, GEOM.in_spatial, GEOM.kernel,
                         GEOM.stride, GEOM.cin, GEOM.cout)
