@@ -176,12 +176,13 @@ class EngineConfig:
 
     ``telemetry`` (optional, a ``repro.obs.Telemetry``) makes the engine
     observable: ``plan`` records cache hit/miss counters and planning
-    time, ``compile_network`` records compile time and wraps its callable
-    with host-side dispatch timing (a pure pass-through under tracing —
-    zero added jaxpr equations).  ``None`` (the default) keeps the engine
-    telemetry-free: no registry is created, no instrument is ever
-    touched.  ``Telemetry`` hashes by identity, so configs stay usable as
-    memoization keys.
+    time (and ``engine_conv_tap_fold_total``, the conv geometries planned
+    onto the conv body's in-plane tap fold), ``compile_network`` records
+    compile time and wraps its callable with host-side dispatch timing (a
+    pure pass-through under tracing — zero added jaxpr equations).
+    ``None`` (the default) keeps the engine telemetry-free: no registry is
+    created, no instrument is ever touched.  ``Telemetry`` hashes by
+    identity, so configs stay usable as memoization keys.
 
     ``tuned_plans`` (optional, a ``repro.tune.TunedPlanCache``) is the
     persisted autotuner output: on a plan-cache miss the engine consults
@@ -363,6 +364,9 @@ class UniformEngine:
                 tel.registry.counter(
                     "engine_plan_tuned_hits_total" if tuned is not None
                     else "engine_plan_heuristic_total").inc()
+                if mode == "conv" and _kcommon.fold_width(
+                        key[2], key[3], dilation, plan.block_co):
+                    tel.registry.counter("engine_conv_tap_fold_total").inc()
                 tel.registry.histogram("engine_plan_seconds").observe(
                     time.perf_counter() - t0)
         elif tel is not None:
@@ -623,6 +627,7 @@ class LayerSchedule:
     dilation: tuple[int, ...] = ()     # per-dim tap spacing
     epilogue: str = "-"                # fused epilogue ("bias+relu" | "-")
     precision: str = "f32"             # resolved Precision.describe()
+    tap_fold: bool = False             # conv body folds in-plane taps
 
     def __post_init__(self):
         if not self.local_cin:
@@ -637,6 +642,8 @@ class LayerSchedule:
         coll = (f" {self.collective}{self.collective_bytes}B"
                 if self.collective else "")
         plan = self.plan.describe() if self.plan is not None else "merge"
+        if self.tap_fold:
+            plan += "+fold"
         return (f"{self.name:<18s} {self.op:<6s} "
                 f"{'x'.join(map(str, self.in_spatial)):>11s}x{self.cin:<4d}-> "
                 f"{'x'.join(map(str, self.out_spatial)):>11s}x{self.cout:<4d} "
@@ -644,7 +651,7 @@ class LayerSchedule:
                 f"d{'x'.join(map(str, self.dilation)):<5s} "
                 f"ep:{self.epilogue:<10s} "
                 f"pr:{self.precision:<8s} "
-                f"{plan:<28s} grid{self.grid_steps:>5d} "
+                f"{plan:<33s} grid{self.grid_steps:>5d} "
                 f"mxu{self.mxu_dispatches:>6d} zeros{self.sparsity:.0%}"
                 f"{coll}")
 
@@ -668,6 +675,7 @@ class LayerSchedule:
             "dilation": list(self.dilation),
             "epilogue": self.epilogue,
             "precision": self.precision,
+            "tap_fold": self.tap_fold,
         }
 
 
@@ -785,7 +793,9 @@ def _schedule_layer(layer: _networks.UniformLayer, engine: UniformEngine,
         local_cin=cin, local_cout=cout, collective=collective,
         collective_bytes=collective_bytes, groups=g,
         dilation=layer.dilation, epilogue=layer.epilogue.describe(),
-        precision=prec.describe())
+        precision=prec.describe(),
+        tap_fold=(layer.op == "conv"
+                  and _kcommon.fold_width(k3, s3, dil3, plan.block_co) > 0))
 
 
 def _schedule_merge(node: _networks.MergeNode, graph: _networks.UniformGraph,

@@ -14,7 +14,9 @@ cannot drift:
   * ``phase_taps`` — the static (phase, valid taps) table; summed over
     phases the taps number exactly K^d (the IOM valid-MAC count),
   * ``phase_major_tap_index`` — the weight gather that lands each phase's
-    taps contiguously, feeding ONE wide MXU matmul per phase.
+    taps contiguously, feeding ONE wide MXU matmul per phase,
+  * ``fold_in_plane`` — whether the conv body moves a phase's in-plane
+    taps into that matmul's contraction (lane-thin output blocks).
 """
 
 from __future__ import annotations
@@ -110,6 +112,42 @@ def phase_taps(kernel, stride, dilation=None):
                 for combo in itertools.product(*dim_taps)]
         out.append((p_idx, p, taps))
     return out
+
+
+# -- In-plane tap folding (lane-thin output blocks) --------------------------
+#
+# Each tap a phase keeps on its matmul's output columns costs one f32
+# read-modify-write of a [rows, block_co] accumulator slab, and a slab
+# narrower than 128 lanes still occupies whole vregs.  For such blocks the
+# conv body folds a phase's in-plane (m_h, m_w) taps into the contraction
+# instead: the input phase is gathered at the in-plane shifts into one
+# [rows, n_plane*bci] slab, and only the leading-dim taps stay on the
+# output columns (n_lead overlap-adds instead of n_lead*n_plane).
+
+def fold_split(taps):
+    """A phase's product-ordered taps as (lead m_d values, in-plane (m_h,
+    m_w) offsets): tap ``i*len(plane) + j`` is ``(lead[i], *plane[j])``."""
+    lead = list(dict.fromkeys(m[0] for m in taps))
+    plane = list(dict.fromkeys(m[1:] for m in taps))
+    assert len(lead) * len(plane) == len(taps), taps
+    return lead, plane
+
+
+def fold_in_plane(taps, block_co: int) -> bool:
+    """Whether the conv body folds this phase's in-plane taps into the
+    contraction: the output block is lane-thin and the phase has more than
+    one in-plane tap.  The ONE decision the kernel body, its byte model and
+    the engine's plan accounting share."""
+    return block_co < 128 and len(fold_split(taps)[1]) > 1
+
+
+def fold_width(kernel, stride, dilation, block_co: int) -> int:
+    """The most in-plane taps any folded phase of a conv geometry gathers
+    at ``block_co`` — the conv body's gather slab width in input-channel
+    blocks; 0 when no phase folds."""
+    return max((len(fold_split(taps)[1])
+                for _, _, taps in phase_taps(kernel, stride, dilation)
+                if fold_in_plane(taps, block_co)), default=0)
 
 
 def phase_major_tap_index(kernel, stride, dilation=None):

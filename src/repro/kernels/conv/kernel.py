@@ -21,7 +21,10 @@ Same fused 4D grid as the deconv forward:
     phase ``x_ph = x[p::S]``, a flattened [dtile*Lh*Lw, bci] slab, feeds ONE
     wide MXU matmul against the phase's valid taps (phase-major weight
     layout) — S^d dispatches per grid step, not K^d.  Stride 1 is the
-    degenerate single phase (one matmul carrying all K^d taps).
+    degenerate single phase (one matmul carrying all K^d taps).  When the
+    output block is under 128 lanes, a phase's in-plane taps move into the
+    contraction instead (``kernels.common.fold_in_plane``): only its
+    leading-dim taps overlap-add into the accumulator.
   * each grid tile owns ``dtile`` output rows and reads the aligned
     ``dtile`` rows of every input phase; when K_d > S_d a tap reaches into
     the NEXT tile's input slab, so the d-tile axis iterates in REVERSE and
@@ -52,6 +55,9 @@ from repro.kernels.common import (
     apply_epilogue,
     compiler_params,
     flat_grid,
+    fold_in_plane,
+    fold_split,
+    fold_width,
     from_tiles,
     halo_depth,
     lift_geometry3,
@@ -63,6 +69,17 @@ from repro.kernels.common import (
     tile_bytes,
     to_phases,
 )
+
+
+def _folded_weight_slab(w_ref, off: int, n_lead: int, n_plane: int, dtype):
+    """A folded phase's taps as ONE [n_plane*a, n_lead*b] operand: block
+    (j, i) is tap ``off + i*n_plane + j`` (see ``fold_split``), so the
+    contraction runs over the in-plane taps and the columns over the
+    leading-dim taps."""
+    rows = [jnp.concatenate([w_ref[off + i * n_plane + j].astype(dtype)
+                             for i in range(n_lead)], axis=1)
+            for j in range(n_plane)]
+    return jnp.concatenate(rows, axis=0)
 
 
 def _conv_kernel_body(*refs, rows, plane, row_w, margin, dtile, halo,
@@ -80,7 +97,8 @@ def _conv_kernel_body(*refs, rows, plane, row_w, margin, dtile, halo,
     b_ref:   [1, bco]                    (only when ``has_bias``)
     o_ref:   [1, 1, rows, bco]           (this tile's output slab)
     acc_ref: VMEM f32 [margin + (dtile + M_d - 1)*plane, bco]
-    halo_ref: VMEM f32 [(M_d - 1)*plane, bco] (None if M_d == 1)
+    halo_ref: VMEM f32 [(M_d - 1)*plane, bco] (only when M_d > 1)
+    xcol_ref: VMEM [rows, n_plane*bci] MXU dtype (only when a phase folds)
 
     Input phase row ``u`` feeds output row ``u - m`` through tap ``m``:
     each phase is ONE matmul whose tap columns overlap-add at the row
@@ -89,6 +107,15 @@ def _conv_kernel_body(*refs, rows, plane, row_w, margin, dtile, halo,
     terms of padding positions).  Accumulator rows ``[margin, margin +
     (M_d-1)*plane)`` belong to the previous tile and ride the reversed
     FIFO-D carry.
+
+    A phase that ``fold_in_plane`` selects (lane-thin ``bco``, several
+    in-plane taps) moves its in-plane shifts to the input side:
+    ``xcol_ref`` gathers the phase read at each in-plane shift
+    ``m_h*Lw + m_w``, side by side, and the matmul contracts
+    ``[rows, n_plane*bci]`` against ``[n_plane*bci, n_lead*bco]``, so only
+    its ``n_lead`` leading-dim taps overlap-add.  A shifted read past the
+    slab's end is zero-filled: those rows feed only outputs whose in-plane
+    position lies past (OH, OW), which the host crops.
 
     The epilogue (scale + bias + activation) runs in ``_flush`` — after the
     Cin adder tree completes AND after the reversed FIFO-D carry-in, so it
@@ -102,7 +129,8 @@ def _conv_kernel_body(*refs, rows, plane, row_w, margin, dtile, halo,
     s_ref = next(it) if has_scale else None
     b_ref = next(it) if has_bias else None
     o_ref, acc_ref = next(it), next(it)
-    halo_ref = next(it, None)
+    halo_ref = next(it) if halo else None
+    xcol_ref = next(it, None)
     r = pl.program_id(2)
     cb = pl.program_id(3)
     cdt = mxu_dtype(x_ref.dtype, w_ref.dtype)
@@ -114,13 +142,31 @@ def _conv_kernel_body(*refs, rows, plane, row_w, margin, dtile, halo,
 
     off = 0
     for p_idx, _, taps in phase_taps(kernel, stride, dilation):
-        x = x_ref[0, 0, p_idx].astype(cdt)          # input phase p
-        # one wide matmul per phase: [rows, bci] x [bci, n_taps*bco]
-        w = phase_weight_slab(w_ref, off, len(taps), cdt)
+        if fold_in_plane(taps, bco):
+            # [rows, n_plane*bci] x [n_plane*bci, n_lead*bco]: the phase
+            # gathered at each in-plane shift, zero past the slab's end
+            lead, in_plane = fold_split(taps)
+            bci = x_ref.shape[-1]
+            for j, (mh, mw) in enumerate(in_plane):
+                cols = pl.ds(j * bci, bci)
+                sh = mh * row_w + mw
+                xcol_ref[pl.ds(0, rows - sh), cols] = \
+                    x_ref[0, 0, p_idx, pl.ds(sh, rows - sh), :].astype(cdt)
+                if sh:
+                    xcol_ref[pl.ds(rows - sh, sh), cols] = \
+                        jnp.zeros((sh, bci), cdt)
+            x = xcol_ref[:, pl.ds(0, len(in_plane) * bci)]
+            w = _folded_weight_slab(w_ref, off, len(lead), len(in_plane), cdt)
+            out_taps = [(md, 0, 0) for md in lead]
+        else:
+            x = x_ref[0, 0, p_idx].astype(cdt)          # input phase p
+            # one wide matmul per phase: [rows, bci] x [bci, n_taps*bco]
+            w = phase_weight_slab(w_ref, off, len(taps), cdt)
+            out_taps = taps
         off += len(taps)
         res = jnp.dot(x, w, preferred_element_type=jnp.float32,
                       precision=mxu_precision(cdt))
-        for t_idx, m in enumerate(taps):
+        for t_idx, m in enumerate(out_taps):
             # y[o] += x_p[o + m] w_tap: row u of the phase lands at u - m;
             # the leading shift reaches into the carry rows at the top
             at = margin + (halo - m[0]) * plane - m[1] * row_w - m[2]
@@ -217,6 +263,10 @@ def conv_pallas_3d(x: jax.Array, w_taps: jax.Array, *,
                           jnp.float32)]
     if halo:
         scratch.append(pltpu.VMEM((halo * plane, block_co), jnp.float32))
+    n_plane = fold_width(kernel, stride, dilation, block_co)
+    if n_plane:
+        scratch.append(pltpu.VMEM((rows, n_plane * block_ci),
+                                  mxu_dtype(x.dtype, w_taps.dtype)))
 
     in_specs = [
         pl.BlockSpec((1, 1, math.prod(stride), rows, block_ci),
@@ -267,8 +317,10 @@ def vmem_bytes(out_spatial, kernel, stride, block_ci, block_co,
     leading-dim tiling counts).  Models, in Mosaic's tiled layout
     (``kernels.common.tile_bytes``), the double-buffered phase-split input,
     weight and output blocks, the f32 accumulator + halo carry, and the
-    widest phase's tap-batched matmul result.  Dilation widens the
-    phase geometry through the effective kernel extent.  The deconv
+    widest phase's f32 matmul result (every tap on its columns, or only
+    the leading-dim taps of a phase ``fold_in_plane`` selects), plus the
+    ``[rows, n_plane*bci]`` gather scratch when a phase folds.  Dilation
+    widens the phase geometry through the effective kernel extent.  The deconv
     backward's dx budget is this same model with the channel roles swapped
     (see ``kernels.deconv.kernel.vmem_bytes_bwd``).  ``w_dtype_bytes`` /
     ``out_dtype_bytes`` default to ``in_dtype_bytes``; quantized plans pass
@@ -277,6 +329,9 @@ def vmem_bytes(out_spatial, kernel, stride, block_ci, block_co,
     w_dtype_bytes = in_dtype_bytes if w_dtype_bytes is None else w_dtype_bytes
     out_dtype_bytes = in_dtype_bytes if out_dtype_bytes is None \
         else out_dtype_bytes
+    # the in-kernel matmul operand width: int8 widens to f32 (mxu_dtype)
+    mxu_bytes = (4 if min(in_dtype_bytes, w_dtype_bytes) == 1
+                 else max(in_dtype_bytes, w_dtype_bytes))
     out_spatial, kernel, stride, dilation = lift_geometry3(
         out_spatial, kernel, stride, dilation)
     m_max = phase_geometry(kernel, stride, dilation)
@@ -287,7 +342,10 @@ def vmem_bytes(out_spatial, kernel, stride, block_ci, block_co,
     plane = grid_hw[0] * grid_hw[1]
     rows = dtile * plane
     margin = (m_max[1] - 1) * grid_hw[1] + m_max[2] - 1
-    taps = math.prod(m_max)
+    res_cols = max(len(fold_split(taps)[0]) if fold_in_plane(taps, block_co)
+                   else len(taps)
+                   for _, _, taps in phase_taps(kernel, stride, dilation))
+    n_plane = fold_width(kernel, stride, dilation, block_co)
     return (2 * math.prod(stride) * tile_bytes(rows, block_ci,
                                                in_dtype_bytes)
             + 2 * math.prod(kernel) * tile_bytes(block_ci, block_co,
@@ -295,5 +353,6 @@ def vmem_bytes(out_spatial, kernel, stride, block_ci, block_co,
             + 2 * tile_bytes(rows, block_co, out_dtype_bytes)
             + tile_bytes(margin + (dtile + halo) * plane, block_co, 4)
             + (tile_bytes(halo * plane, block_co, 4) if halo else 0)
-            # the widest phase's tap-batched matmul result (f32)
-            + tile_bytes(rows, taps * block_co, 4))
+            + tile_bytes(rows, res_cols * block_co, 4)
+            + (tile_bytes(rows, n_plane * block_ci, mxu_bytes)
+               if n_plane else 0))

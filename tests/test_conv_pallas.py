@@ -13,10 +13,13 @@ import jax.numpy as jnp
 from repro.core.jaxpr_utils import count_prims as _count_prims
 from repro.core.jaxpr_utils import pallas_eqns as _pallas_eqns
 from repro.core import conv_nd, conv_output_shape
+from repro.core.engine import EngineConfig, UniformEngine
 from repro.core.tiling import plan_uniform_tiles
 from repro.kernels.conv import conv, conv_reference
 from repro.kernels.conv.kernel import vmem_bytes as conv_vmem_bytes
 from repro.kernels.conv.ref import conv_loop_oracle
+from repro.kernels.common import fold_width
+from repro.obs import Telemetry
 
 # The satellite acceptance sweep: rank {1,2,3} x stride {1,2} x padding
 # {0, 1, (0,1)} — every cell parity-checked against the XLA conv engine.
@@ -37,14 +40,82 @@ def _sweep_case(rng, rank, stride, pad):
     return x, w, (stride,) * rank, padding
 
 
-@pytest.mark.parametrize("rank,stride,pad", SWEEP)
-def test_conv_matches_lax(rng, rank, stride, pad):
-    x, w, S, P = _sweep_case(rng, rank, stride, pad)
-    ref = conv_reference(x, w, S, P)
-    got = conv(x, w, S, P)
+# In-plane tap folding: a lane-thin output block (cout < 128) folds each
+# phase's in-plane taps into the contraction; at cout 128 every tap stays
+# on the output columns.  name -> (rank, stride, pad, cin, cout, extra);
+# extra: kernel, dilation, groups, int8 operands, a VMEM budget that
+# forces the multi-tile grid, and whether the geometry must fold.
+FOLD_SWEEP = {
+    "fold-r3-s1-cin1": (3, 1, 1, 1, 16, {}),
+    "fold-r3-s1-cin32": (3, 1, 1, 32, 16, {}),
+    "fold-r2-s1-cin32": (2, 1, 1, 32, 16, {}),
+    "fold-r2-s2-cin1": (2, 2, 1, 1, 16, {}),
+    "fold-r3-s2-cin32": (3, 2, "lohi", 32, 16, {}),
+    "fold-r2-s1-deep-halo": (2, 1, 1, 2, 16,
+                             dict(kernel=(7, 3), max_tile_bytes=8 * 1024)),
+    "fold-r3-dilation2": (3, 1, 2, 8, 16, dict(dilation=2)),
+    "fold-r2-groups2": (2, 1, 1, 32, 16, dict(groups=2)),
+    "fold-r3-int8": (3, 1, 1, 32, 16, dict(int8=True)),
+    "nofold-r3-cout128": (3, 1, 1, 32, 128, dict(fold=False)),
+}
+
+
+def _fold_case(rng, rank, stride, pad, cin, cout, extra):
+    I = {2: (19, 5), 3: (7, 6, 5)}[rank] if "max_tile_bytes" in extra \
+        else {2: (9, 8), 3: (6, 5, 4)}[rank]
+    K = extra.get("kernel", (3,) * rank)
+    g = extra.get("groups", 1)
+    padding = ((0, 1),) * rank if pad == "lohi" else pad
+    x = rng.randn(2, *I, cin)
+    w = rng.randn(*K, cin // g, cout)
+    kw = {k: extra[k] for k in ("dilation", "groups") if k in extra}
+    if extra.get("int8"):
+        x, w = (jnp.asarray(np.clip(np.round(40 * a), -127, 127), jnp.int8)
+                for a in (x, w))
+        kw["w_scale"] = jnp.asarray(0.01 + rng.rand(cout), jnp.float32)
+    else:
+        x, w = jnp.asarray(x, jnp.float32), jnp.asarray(w, jnp.float32)
+    return x, w, (stride,) * rank, padding, kw
+
+
+CONV_CASES = (
+    [pytest.param(*case, None, id=f"{case[0]}-{case[1]}-{case[2]}")
+     for case in SWEEP]
+    + [pytest.param(None, None, None, name, id=name) for name in FOLD_SWEEP])
+
+
+@pytest.mark.parametrize("rank,stride,pad,fold_case", CONV_CASES)
+def test_conv_matches_lax(rng, rank, stride, pad, fold_case):
+    if fold_case is None:
+        x, w, S, P = _sweep_case(rng, rank, stride, pad)
+        ref = conv_reference(x, w, S, P)
+        got = conv(x, w, S, P)
+        assert got.shape == ref.shape
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=1e-4, atol=1e-4)
+        return
+    *geom, extra = FOLD_SWEEP[fold_case]
+    x, w, S, P, kw = _fold_case(rng, *geom, extra)
+    tel = Telemetry()
+    eng = UniformEngine(EngineConfig(
+        method="pallas", telemetry=tel,
+        max_tile_bytes=extra.get("max_tile_bytes")))
+    got = eng.conv(x, w, S, P, **kw)
+    # int8 operands: the integer sums are exact in f32, then one rescale
+    scale = kw.pop("w_scale", 1.0)
+    ref = UniformEngine("xla").conv(x.astype(jnp.float32),
+                                    w.astype(jnp.float32), S, P, **kw) * scale
     assert got.shape == ref.shape
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-4, atol=1e-4)
+    # which body ran: the engine counts the folded geometries it plans
+    folded = tel.registry.get("engine_conv_tap_fold_total")
+    assert (folded.value if folded else 0) == int(extra.get("fold", True))
+    (key, plan), = eng.plan_cache.items()
+    assert (fold_width(key[2], key[3], key[7], plan.block_co) > 0) == \
+        extra.get("fold", True)
+    if "max_tile_bytes" in extra:
+        assert plan.n_dtiles > 1
 
 
 @pytest.mark.parametrize("rank,stride,pad", [(2, 2, 1), (3, 1, "lohi"),

@@ -58,9 +58,15 @@ def test_vnet_graph_schedules_merges_and_epilogues():
         or "relu" in rows["vnet.enc1"].epilogue
     text = report.describe()
     assert "ep:" in text and "concat" in text
+    # lane-thin 3x3x3 convs fold their in-plane taps; the 1x1x1 head has
+    # one in-plane tap and the deconvs run their own body
+    assert rows["vnet.enc1"].tap_fold and rows["vnet.merge2"].tap_fold
+    assert not rows["vnet.head"].tap_fold
+    assert not any(l.tap_fold for l in report.layers if l.op == "deconv")
+    assert text.count("+fold") == sum(l.tap_fold for l in report.layers)
     js = report.to_json()
     for row in js["layers"]:
-        assert {"groups", "dilation", "epilogue"} <= set(row)
+        assert {"groups", "dilation", "epilogue", "tap_fold"} <= set(row)
 
 
 def test_graph_report_carries_groups_and_dilation():

@@ -38,7 +38,19 @@ LAYERS = {
                  ((0, 1),) * 3),
     # networks.vnet_graph() head: 1x1x1 conv 16 -> 2 classes
     "vnet_head": ("conv", (1, 32, 32, 16, 16), (1, 1, 1, 16, 2), 1, 0),
+    # networks.vnet_graph() enc1 and merge4 at the published 128x128x64:
+    # 16-lane outputs, so the conv body folds the in-plane taps
+    "vnet_enc1": ("conv", (1, 128, 128, 64, 1), (3, 3, 3, 1, 16), 1, 1),
+    "vnet_merge4": ("conv", (1, 128, 128, 64, 32), (3, 3, 3, 32, 16), 1, 1),
 }
+# Served at full resolution, not trained there: one leading-dim row of the
+# dw kernel's working set is over the scoped-VMEM cap at 128x128x64.
+FORWARD_ONLY = {"vnet_enc1", "vnet_merge4"}
+CASES = [pytest.param(layer, dtype, mode, id=f"{layer}-{dtype}-{mode}")
+         for layer in sorted(LAYERS)
+         for dtype in ("float32", "bfloat16")
+         for mode in ("fwd", "grad")
+         if mode == "fwd" or layer not in FORWARD_ONLY]
 
 
 @pytest.fixture(scope="module")
@@ -66,9 +78,7 @@ def _compile(fn, args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("mode", ["fwd", "grad"])
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("layer", sorted(LAYERS))
+@pytest.mark.parametrize("layer,dtype,mode", CASES)
 def test_engine_op_compiles_for_v5e(one_chip, engine, layer, dtype, mode):
     op, xs, ws, stride, padding = LAYERS[layer]
     dt = jnp.dtype(dtype)
@@ -98,3 +108,33 @@ def test_int8_weight_deconv_compiles_for_v5e(one_chip, engine):
     text = _compile(lambda x, w, s: engine.deconv(x, w, stride, padding,
                                                   w_scale=s), args)
     assert "tpu_custom_call" in text
+
+
+def test_merge4_plan_covers_kernel_vmem(one_chip, monkeypatch):
+    """V-Net merge4 at 128x128x64 (bf16, the served cell's budget): the
+    planned step fits the 48 MiB budget under ``strict_vmem``, and Mosaic
+    lowers the folded kernel with its scoped VMEM capped at exactly the
+    planned ``step_vmem_bytes`` — the byte model covers what the kernel
+    allocates."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.kernels.conv import kernel as conv_kernel
+    from repro.kernels.common import fold_width
+
+    budget = 48 << 20
+    eng = UniformEngine(EngineConfig(method="pallas", interpret=False,
+                                     strict_vmem=True, max_tile_bytes=budget))
+    _, xs, ws, stride, padding = LAYERS["vnet_merge4"]
+    plan = eng.plan("conv", (130, 130, 66), (3, 3, 3), (1, 1, 1), 32, 16)
+    assert plan.dtile == 1 and plan.block_co == 16
+    assert fold_width((3, 3, 3), (1, 1, 1), (1, 1, 1), plan.block_co) == 9
+    assert plan.step_vmem_bytes <= budget
+    monkeypatch.setattr(
+        conv_kernel, "compiler_params",
+        lambda step, sem: pltpu.CompilerParams(
+            dimension_semantics=tuple(sem),
+            vmem_limit_bytes=plan.step_vmem_bytes))
+    args = (jax.ShapeDtypeStruct(xs, jnp.bfloat16, sharding=one_chip),
+            jax.ShapeDtypeStruct(ws, jnp.bfloat16, sharding=one_chip))
+    text = _compile(lambda x, w: eng.conv(x, w, stride, padding), args)
+    assert text.count("tpu_custom_call") >= 1
